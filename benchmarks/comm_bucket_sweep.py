@@ -286,8 +286,9 @@ _MEASURE_SNIPPET = """
 
 def measured_rows(backend: str = "lax", devices: int = MEASURED_DEVICES):
     """Wall-clock the real ``FlatSchedule(backend)`` bucket round-trip over
-    the vgg-a SMOKE tree on ``devices`` forced host devices (subprocess so
-    the forced device count never leaks into the caller), per wire format,
+    the vgg-a SMOKE tree on ``devices`` forced host CPU devices (subprocess
+    so the forced device count never leaks into the caller, and it never
+    takes a chip — these rows are CPU figures), per wire format,
     paired with the §3.2 model's prediction for the same plan in the
     derived column.  Adds per-format measured CROSSOVER rows: the smallest
     measured bucket where the compressed roundtrip actually beats fp32
@@ -297,11 +298,12 @@ def measured_rows(backend: str = "lax", devices: int = MEASURED_DEVICES):
     tracks)."""
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
         PYTHONPATH=os.pathsep.join(
             p for p in (os.path.join(os.path.dirname(__file__), "..", "src"),
                         os.environ.get("PYTHONPATH")) if p))
-    code = "import repro.jaxcompat\n" + textwrap.dedent(
+    code = textwrap.dedent(
         _MEASURE_SNIPPET.format(backend=backend, devices=devices,
                                 mibs=MEASURED_MIB, fmts=MEASURED_FORMATS))
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import repro.jaxcompat  # noqa: F401  (backfills AxisType & co on jax 0.4.x)
 from jax.sharding import AxisType
 
 from repro.configs import get_config, smoke_variant, XEON_E5_2697V3

@@ -8,5 +8,3 @@ balance-equation-driven placement, and blocking-solver-driven Pallas kernels
 families.  See DESIGN.md.
 """
 __version__ = "1.0.0"
-
-from repro import jaxcompat  # noqa: E402,F401  (backfills jax>=0.6 APIs on 0.4.x)

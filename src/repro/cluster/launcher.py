@@ -2,9 +2,13 @@
 
 Spawns N worker processes, each a fresh ``python -m repro.launch.cluster``
 interpreter with the :class:`~repro.cluster.spec.ClusterSpec` env vars set
-(and ``XLA_FLAGS=--xla_force_host_platform_device_count=<local>`` exported
+(and ``JAX_PLATFORMS=cpu`` plus
+``XLA_FLAGS=--xla_force_host_platform_device_count=<local>`` exported
 BEFORE the worker imports jax — device counts are fixed at backend init, so
-they can only be chosen from outside the process).  Worker 0 inherits the
+they can only be chosen from outside the process).  This is a CPU tool: the
+workers are gloo processes, and on a TPU host none of them may take a chip
+(a chip belongs to one process; one process drives all of a host's chips).
+Worker 0 inherits the
 launcher's stdout (live progress); the others log to files in the run
 directory, printed back on failure.
 
@@ -43,6 +47,9 @@ from repro.telemetry.autotune import ENV_AUTOTUNE_CACHE
 
 ENV_HEARTBEAT_FILE = "REPRO_HEARTBEAT_FILE"
 ENV_RESULT_FILE = "REPRO_RESULT_FILE"
+#: the JAX platform of every worker (and of the supervisor's --verify
+#: reference, which must run where the workers do)
+WORKER_PLATFORM = "cpu"
 
 
 class Heartbeat(NamedTuple):
@@ -182,8 +189,9 @@ def _worker_env(spec: ClusterSpec, hb_file: str,
         # every worker shares one per-run comm=auto plan cache; an elastic
         # relaunch at the same topology skips the probe (telemetry.autotune)
         env[ENV_AUTOTUNE_CACHE] = autotune_cache_path(run_dir)
-    # the forced host device count must be in place before the worker's
-    # first jax import; append so user-set XLA flags survive
+    # platform and forced host device count must be in place before the
+    # worker's first jax import; append so user-set XLA flags survive
+    env["JAX_PLATFORMS"] = WORKER_PLATFORM
     flag = (f"--xla_force_host_platform_device_count="
             f"{spec.local_devices}")
     prev = env.get("XLA_FLAGS", "")

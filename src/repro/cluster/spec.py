@@ -11,12 +11,10 @@ pattern, so nothing in the training path knows how processes were placed.
                           (process 0 binds it).
 ``REPRO_NUM_PROCESSES``   world size.
 ``REPRO_PROCESS_ID``      this process's rank in [0, num_processes).
-``REPRO_LOCAL_DEVICES``   devices this process contributes.  On the CPU
-                          containers this is realized by forcing
-                          ``--xla_force_host_platform_device_count`` (the
-                          launcher exports it BEFORE the worker imports
-                          jax); on an accelerator host it is informative
-                          only (the local chips are what they are).
+``REPRO_LOCAL_DEVICES``   devices this process contributes, realized by
+                          forcing ``--xla_force_host_platform_device_count``
+                          (the launcher runs its workers on the CPU and
+                          exports both BEFORE the worker imports jax).
 """
 from __future__ import annotations
 

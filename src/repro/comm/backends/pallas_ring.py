@@ -26,11 +26,11 @@ Operates on the schedules' canonical 1-D fusion buffers (``dim == 0``);
 buffer sizes are strip multiples by construction (``repro.comm.bucketer``
 pads every bucket to the group size).  On CPU the hop kernel runs in
 interpret mode (auto-detected), which is what the equivalence tests
-exercise.  The COMPILED Mosaic path (interpret=False, auto-selected on
-TPU) has not been exercised — this container is CPU-only — and chunk
-sizes here are arbitrary (padded_size/G), not lane-aligned; first TPU
-bring-up should expect to pad hop blocks to (8, 128) tiles (tracked in
-ROADMAP next to the remote-DMA ring).
+exercise; on TPU (``interpret=False``) the hop kernels compile for Mosaic.
+Each bucket's ``(G, padded_size/G)`` chunks are zero-padded once to the
+kernels' ``(R, 128)`` tile layout (``kernels.ring.to_tiles``) and the pad
+is stripped from the owned strip, so strip sizes are unchanged
+(``tests/test_tpu_compile.py`` compiles the 4-device step for a v5e).
 
 **Compressed wire formats** (``wire_format``, bound by the schedule layer
 via ``bind_wire_format``): ``"int8"`` replaces the hop combine with
@@ -54,7 +54,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.collectives import AxisNames, axis_size, flat_group_index, flatten_pad, unflatten
-from repro.kernels.ring import int8_quantize, ring_hop_accum, ring_hop_int8, ring_hop_topk
+from repro.kernels.ring import (
+    from_tiles,
+    int8_quantize,
+    ring_hop_accum,
+    ring_hop_int8,
+    ring_hop_topk,
+    to_tiles,
+)
 
 
 def _ring_perm(G: int) -> List[Tuple[int, int]]:
@@ -107,20 +114,24 @@ class PallasRingBackend:
             raise ValueError(
                 f"buffer size {x.size} not a strip multiple of group {G}")
         p = flat_group_index(axis_name)
-        chunks = x.reshape(G, x.size // G)
+        n = x.size // G
+        # one pad per bucket, to the hop kernels' lane-dense tile layout;
+        # the pad is stripped from the owned strip, so strip sizes (and the
+        # bucket layout every checkpoint records) never see it
+        chunks = to_tiles(x.reshape(G, n))
         perm = _ring_perm(G)
         if self.wire_format == "int8":
-            return self._part_reduce_int8(chunks, axis_name, p, perm)
+            return self._part_reduce_int8(chunks, n, axis_name, p, perm)
         if self.wire_format == "topk":
-            return self._part_reduce_topk(chunks, axis_name, p, perm)
+            return self._part_reduce_topk(chunks, n, axis_name, p, perm)
         send = chunks[jnp.mod(p - 1, G)]
         for s in range(G - 1):
             recv = lax.ppermute(send, axis_name, perm=perm)
             c = jnp.mod(p - 2 - s, G)
             send = ring_hop_accum(chunks, recv, c, interpret=self.interpret)
-        return send
+        return from_tiles(send, n)
 
-    def _part_reduce_int8(self, chunks, axis_name, p, perm) -> jax.Array:
+    def _part_reduce_int8(self, chunks, n, axis_name, p, perm) -> jax.Array:
         """The same ring with (int8, scale) wire messages; every combine is
         the fused dequantize-accumulate-requantize hop kernel."""
         G = chunks.shape[0]
@@ -133,17 +144,19 @@ class PallasRingBackend:
             c = jnp.mod(p - 2 - step, G)
             q, s = ring_hop_int8(chunks, qr, sr, c, interpret=self.interpret)
         # the owned strip leaves the wire once, at the very end
-        return q.astype(jnp.float32) * s[0]
+        return from_tiles(q, n).astype(jnp.float32) * s[0]
 
-    def _part_reduce_topk(self, chunks, axis_name, p, perm) -> jax.Array:
+    def _part_reduce_topk(self, chunks, n, axis_name, p, perm) -> jax.Array:
         """The same ring with (values, indices) sparse messages; the hop
-        kernel scatter-adds them dense, re-selection precedes each forward
-        (never the final hop — the owned strip keeps the dense sum)."""
-        G, n = chunks.shape
+        scatter-adds them dense, re-selection precedes each forward (never
+        the final hop — the owned strip keeps the dense sum).  Selection
+        runs on the unpadded chunk, so k and the indices are the lax
+        backend's."""
+        G = chunks.shape[0]
         chunks = chunks.astype(jnp.float32)
         k = topk_chunk_k(n, self.topk_ratio)
-        vals, idx = _topk_select(chunks[jnp.mod(p - 1, G)], k)
         dense = chunks[jnp.mod(p - 1, G)]
+        vals, idx = _topk_select(from_tiles(dense, n), k)
         for step in range(G - 1):
             vr = lax.ppermute(vals, axis_name, perm=perm)
             ir = lax.ppermute(idx, axis_name, perm=perm)
@@ -151,8 +164,8 @@ class PallasRingBackend:
             dense = ring_hop_topk(chunks, vr, ir, c,
                                   interpret=self.interpret)
             if step < G - 2:
-                vals, idx = _topk_select(dense, k)
-        return dense
+                vals, idx = _topk_select(from_tiles(dense, n), k)
+        return from_tiles(dense, n)
 
     def part_broadcast(self, x: jax.Array, axis_name: AxisNames,
                        dim: int = 0) -> jax.Array:

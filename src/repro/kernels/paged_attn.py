@@ -15,12 +15,15 @@ like ``kernels.flash_attention`` accumulates across kv blocks; pages fully
 outside the valid set (beyond ``lengths`` or, for sliding-window layers,
 older than the retention window) are skipped with ``pl.when``.
 
+The math is 2-D dots per kv head — the g query heads that share it
+against the page's (ps, D) keys and values — over free reshapes of q to
+(B, Hkv, g, D) and of the pool to (P, ps, Hkv*D), so no GQA repeat and no
+batched in-kernel einsum reaches Mosaic.
+
 Correctness contract: ``kernels.ref.paged_decode_attention_ref``, swept in
 tests/test_kernels.py under interpret mode (auto-enabled off-TPU, as with
-the ring kernels).  As with the ring, the compiled Mosaic path is
-unexercised on this CPU container: the in-kernel GQA ``jnp.repeat`` and the
-(Hq, ps) score shapes likely want (8, 128)-tile padding for a first real-TPU
-bring-up.
+the ring kernels).  ``tests/test_tpu_compile.py`` compiles the kernel for a
+v5e at a served model's head count, kv heads and head_dim.
 """
 from __future__ import annotations
 
@@ -43,7 +46,8 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
 
 def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, ps: int, n_pages: int,
-                  window: int, softcap: float, scale: float, g: int):
+                  window: int, softcap: float, scale: float, hkv: int,
+                  d: int):
     b, i = pl.program_id(0), pl.program_id(1)
 
     @pl.when(i == 0)
@@ -61,29 +65,31 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(needed)
     def _body():
-        q = q_ref[0].astype(jnp.float32) * scale          # (Hq, D)
-        k = k_ref[0].astype(jnp.float32)                  # (ps, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)
-        if g > 1:
-            k = jnp.repeat(k, g, axis=1)                  # (ps, Hq, D)
-            v = jnp.repeat(v, g, axis=1)
-        s = jnp.einsum("hd,phd->hp", q, k)                # (Hq, ps)
-        if softcap > 0:
-            s = jnp.tanh(s / softcap) * softcap
-        Hq = q.shape[0]
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (Hq, ps), 1)
+        g = q_ref.shape[2]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
         mask = pos < length
         if window > 0:
             mask = jnp.logical_and(mask, pos > length - 1 - window)
-        s = jnp.where(mask, s, NEG_INF)
+        # one kv head at a time: its g query heads against the page's
+        # (ps, D) keys and values, as plain 2-D dots
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32) * scale          # (g, D)
+            k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)  # (ps, D)
+            v = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if softcap > 0:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(mask, s, NEG_INF)                      # (g, ps)
 
-        m_prev = m_ref[...]                               # (Hq, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum("hp,phd->hd", p, v)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                                    # (g, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p.sum(-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(i == n_pages - 1)
     def _finalize():
@@ -107,31 +113,38 @@ def paged_decode_attention(q: jax.Array, pages_k: jax.Array,
         raise ValueError(f"GQA needs Hq % Hkv == 0, got {Hq}/{Hkv}")
     g = Hq // Hkv
     scale = D ** -0.5
+    # free reshapes: query heads grouped by their kv head, and each page
+    # row's kv heads side by side on the lane axis
+    qg = q.reshape(B, Hkv, g, D)
+    pk = pages_k.reshape(P, ps, Hkv * D)
+    pv = pages_v.reshape(P, ps, Hkv * D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # page_table, lengths
         grid=(B, n),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, i, pt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, D),
-                         lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, D),
-                         lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, g, D), lambda b, i, pt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, ps, Hkv * D),
+                         lambda b, i, pt, ln: (pt[b, i], 0, 0)),
+            pl.BlockSpec((1, ps, Hkv * D),
+                         lambda b, i, pt, ln: (pt[b, i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, i, pt, ln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, g, D),
+                               lambda b, i, pt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hq, D), jnp.float32),
-            pltpu.VMEM((Hq, 1), jnp.float32),
-            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hkv, g, D), jnp.float32),
+            pltpu.VMEM((Hkv, g, 1), jnp.float32),
+            pltpu.VMEM((Hkv, g, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_kernel, ps=ps, n_pages=n, window=window,
-        softcap=logit_softcap, scale=scale, g=g)
-    return pl.pallas_call(
+        softcap=logit_softcap, scale=scale, hkv=Hkv, d=D)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=_auto_interpret(interpret),
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      q, pages_k, pages_v)
+      qg, pk, pv)
+    return out.reshape(B, Hq, D)

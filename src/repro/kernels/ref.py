@@ -91,10 +91,13 @@ def ring_all_gather_ref(strips: jax.Array) -> jax.Array:
 def int8_quantize_ref(x: jax.Array):
     """Oracle for ``kernels.ring.int8_quantize``: symmetric per-message
     max-abs quantization.  Returns ``(q int8 (n,), scale f32 (1,))`` with
-    ``scale = max|x| / 127`` (1.0 for an all-zero message so dequantize is
-    well defined); round-to-nearest keeps ``|q| <= 127`` by construction."""
+    ``scale = max|x| * (1/127)`` (1.0 for an all-zero message so dequantize
+    is well defined); round-to-nearest keeps ``|q| <= 127`` by construction.
+    The scale is a product with the f32 reciprocal, not a quotient: XLA may
+    rewrite a division by a constant into that product in one program and
+    not in another, and the kernel must match this oracle bit for bit."""
     xf = x.astype(jnp.float32)
-    s = jnp.max(jnp.abs(xf)) / 127.0
+    s = jnp.max(jnp.abs(xf)) * (1.0 / 127.0)
     s = jnp.where(s > 0, s, 1.0)
     q = jnp.round(xf / s).astype(jnp.int8)
     return q, s.reshape(1)

@@ -25,7 +25,11 @@ ring_collective_time``).  This module implements that schedule explicitly:
     the chunk index prefetched as a scalar — used by
     ``repro.comm.backends.PallasRingBackend`` inside ``shard_map``: there
     the neighbor exchange itself is a ``lax.ppermute`` (XLA's ICI neighbor
-    DMA), and this kernel is the compute the ring overlaps with it.
+    DMA), and this kernel is the compute the ring overlaps with it.  The hop
+    kernels take chunks in a lane-dense ``(R, 128)`` tile layout
+    (:func:`to_tiles`) and walk it with a grid of bounded blocks, so they
+    compile for Mosaic at any bucket size (``tests/test_tpu_compile.py``
+    compiles them for a v5e at 4 MiB and 64 MiB buckets).
 
 Chunk/owner convention (must match ``lax.psum_scatter(tiled=True)`` so the
 backends are interchangeable): the buffer splits into G equal chunks along
@@ -50,12 +54,11 @@ hierarchical schedule always runs fp32 — see ``repro.comm.schedule``).
     (values, indices) sparse message dense and adds the local partial; the
     top-k RE-selection for the next hop is plain ``lax.top_k`` in the
     backend (selection is not a memory-bound combine, fusing it buys
-    nothing).  Like the stacked ring, these run under interpret mode on
-    this container; Mosaic bring-up shares the (8, 128)-tile padding TODO
-    of the hop kernel (ROADMAP, PR 4 remainder).
+    nothing).  The int8 scale is written through SMEM.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -166,36 +169,83 @@ def ring_all_gather(strips: jax.Array, *,
 # ---------------------------------------------------------------------------
 # the per-hop combine of the distributed ring (used inside shard_map)
 # ---------------------------------------------------------------------------
+#: the hop kernels work on lane-dense tiles: a chunk of n elements is laid
+#: out as ``(R, LANES)`` rows, R a multiple of ``ROW_ALIGN`` (the int8
+#: sublane tile, the strictest dtype on the wire) and split into equal
+#: blocks of at most ``MAX_BLOCK_ROWS`` rows — the grid walks the blocks, so
+#: the VMEM a hop uses is bounded by the block (512 KiB of f32), never by
+#: the bucket size.
+LANES = 128
+ROW_ALIGN = 32
+MAX_BLOCK_ROWS = 1024
+
+
+def tile_rows(n: int) -> int:
+    """Rows R of the ``(R, LANES)`` tile layout of an n-element chunk: the
+    fewest blocks of at most ``MAX_BLOCK_ROWS`` rows, each rounded up to
+    ``ROW_ALIGN`` — the pad stays under ``ROW_ALIGN`` rows per block."""
+    rows = max(pl.cdiv(n, LANES), 1)
+    nblocks = pl.cdiv(rows, MAX_BLOCK_ROWS)
+    block = pl.cdiv(pl.cdiv(rows, nblocks), ROW_ALIGN) * ROW_ALIGN
+    return nblocks * block
+
+
+def to_tiles(x: jax.Array) -> jax.Array:
+    """Zero-pad the last axis (n elements) to the tile layout: ``(..., n)``
+    -> ``(..., tile_rows(n), LANES)``."""
+    n = x.shape[-1]
+    pad = tile_rows(n) * LANES - n
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(x.shape[:-1] + (-1, LANES))
+
+
+def from_tiles(t: jax.Array, n: int) -> jax.Array:
+    """Inverse of :func:`to_tiles`: flatten the tiles and strip the pad."""
+    return t.reshape(t.shape[:-2] + (-1,))[..., :n]
+
+
+def _blocks(rows: int) -> tuple:
+    """(block rows, number of blocks) of an R-row tile layout."""
+    nb = pl.cdiv(rows, MAX_BLOCK_ROWS)
+    br = rows // nb
+    if rows % nb or br % ROW_ALIGN:
+        raise ValueError(f"{rows} rows is not a tile layout (see tile_rows)")
+    return br, nb
+
+
 def _hop_accum_kernel(c_ref, chunk_ref, recv_ref, out_ref):
-    # chunk_ref is the (1, n) block the index map selected with the
+    # chunk_ref is block j of the chunk the index map selected with the
     # prefetched chunk index — the rest of the local buffer never moves
-    out_ref[...] = recv_ref[...] + chunk_ref[0]
+    del c_ref
+    out_ref[...] = recv_ref[...] + chunk_ref[0].astype(recv_ref.dtype)
 
 
 def ring_hop_accum(chunks: jax.Array, recv: jax.Array, c: jax.Array, *,
                    interpret: Optional[bool] = None) -> jax.Array:
     """One ring hop: add this member's local partial of chunk ``c`` (a
     traced index — it depends on ``lax.axis_index``) to the partial just
-    received from the left neighbor.  ``chunks`` is ``(G, n)``, ``recv``
-    and the result are ``(n,)``.
+    received from the left neighbor.  ``chunks`` is ``(G, R, LANES)`` and
+    ``recv`` and the result are ``(R, LANES)`` (:func:`to_tiles` layout);
+    the sum is in ``recv``'s dtype.
 
     ``c`` rides in as a scalar-prefetch argument driving the chunks
-    BlockSpec index map, so only the selected ``(1, n)`` block is brought
-    into VMEM per hop — O(n) traffic, not O(G*n) (the G-1 hops of one
-    reduce would otherwise stream the whole buffer G-1 times)."""
+    BlockSpec index map, so only blocks of the selected chunk are brought
+    into VMEM — O(n) traffic per hop, not O(G*n)."""
     from jax.experimental.pallas import tpu as pltpu
-    G, n = chunks.shape
+    br, nb = _blocks(chunks.shape[1])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, n), lambda i, c_ref: (c_ref[0], 0)),
-                  pl.BlockSpec((n,), lambda i, c_ref: (0,))],
-        out_specs=pl.BlockSpec((n,), lambda i, c_ref: (0,)),
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((1, br, LANES), lambda j, c_ref: (c_ref[0], j, 0)),
+                  pl.BlockSpec((br, LANES), lambda j, c_ref: (j, 0))],
+        out_specs=pl.BlockSpec((br, LANES), lambda j, c_ref: (j, 0)),
     )
     return pl.pallas_call(
         _hop_accum_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(recv.shape, recv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=_auto_interpret(interpret),
     )(jnp.asarray(c, jnp.int32).reshape(1), chunks, recv)
 
@@ -203,37 +253,82 @@ def ring_hop_accum(chunks: jax.Array, recv: jax.Array, c: jax.Array, *,
 # ---------------------------------------------------------------------------
 # compressed wire formats fused into the hop (CommConfig.wire_format)
 # ---------------------------------------------------------------------------
-def _int8_quantize_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)
-    s = jnp.max(jnp.abs(x)) / 127.0
-    s = jnp.where(s > 0, s, 1.0)   # all-zero message: keep dequant defined
-    q_ref[...] = jnp.round(x / s).astype(jnp.int8)
-    s_ref[0] = s
+def _int8_requant_kernel(c_ref, chunk_ref, *refs, has_msg: bool):
+    """Grid ``(2, blocks)``.  Phase 0 folds ``max|acc|`` of every block into
+    an SMEM scalar; phase 1 recomputes each block's ``acc`` and writes it
+    quantized against ``scale = max|acc| * (1/127)`` (the scale is a property
+    of the whole message, so the grid reads the inputs twice rather than
+    holding the chunk in VMEM)."""
+    del c_ref
+    if has_msg:
+        q_ref, s_ref, qout_ref, sout_ref, amax_ref = refs
+    else:
+        qout_ref, sout_ref, amax_ref = refs
+    phase, j = pl.program_id(0), pl.program_id(1)
+    acc = chunk_ref[0].astype(jnp.float32)
+    if has_msg:
+        acc = q_ref[...].astype(jnp.float32) * s_ref[0] + acc
+
+    @pl.when(jnp.logical_and(phase == 0, j == 0))
+    def _():
+        amax_ref[0] = jnp.float32(0.0)
+
+    @pl.when(phase == 0)
+    def _():
+        amax_ref[0] = jnp.maximum(amax_ref[0], jnp.max(jnp.abs(acc)))
+
+    @pl.when(phase == 1)
+    def _():
+        s = amax_ref[0] * (1.0 / 127.0)
+        s = jnp.where(s > 0, s, 1.0)   # all-zero message: keep dequant defined
+        qout_ref[...] = jnp.round(acc / s).astype(jnp.int8)
+
+        @pl.when(j == 0)
+        def _():
+            sout_ref[0] = s
+
+
+def _int8_requant(chunks, c, q=None, scale=None, *, interpret=None):
+    from jax.experimental.pallas import tpu as pltpu
+    R = chunks.shape[1]
+    br, nb = _blocks(R)
+    has_msg = q is not None
+    in_specs = [pl.BlockSpec((1, br, LANES),
+                             lambda p, j, c_ref: (c_ref[0], j, 0))]
+    args = [chunks]
+    if has_msg:
+        in_specs += [pl.BlockSpec((br, LANES), lambda p, j, c_ref: (j, 0)),
+                     pl.BlockSpec(memory_space=pltpu.SMEM)]
+        args += [q, scale]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(2, nb),
+        in_specs=in_specs,
+        # phase 0 parks the output on block 0 without writing it; phase 1
+        # then visits every block once, so each is written back exactly once
+        out_specs=[pl.BlockSpec((br, LANES), lambda p, j, c_ref: (j * p, 0)),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
+        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_int8_requant_kernel, has_msg=has_msg),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((R, LANES), jnp.int8),
+                   jax.ShapeDtypeStruct((1,), jnp.float32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_auto_interpret(interpret),
+    )(jnp.asarray(c, jnp.int32).reshape(1), *args)
 
 
 def int8_quantize(x: jax.Array, *,
                   interpret: Optional[bool] = None) -> tuple:
-    """Quantize a 1-D f32 message to ``(q int8 (n,), scale f32 (1,))``
-    with a symmetric per-message max-abs scale (``kernels.ref.
-    int8_quantize_ref`` is the oracle).  Used for the FIRST send of the
-    int8 ring — every later hop re-quantizes inside ``ring_hop_int8``."""
-    n, = x.shape
-    return pl.pallas_call(
-        _int8_quantize_kernel,
-        out_shape=(jax.ShapeDtypeStruct((n,), jnp.int8),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)),
-        interpret=_auto_interpret(interpret),
-    )(x)
-
-
-def _hop_int8_kernel(c_ref, chunk_ref, q_ref, s_ref, qout_ref, sout_ref):
-    del c_ref  # consumed by the chunk BlockSpec index map
-    acc = q_ref[...].astype(jnp.float32) * s_ref[0] \
-        + chunk_ref[0].astype(jnp.float32)
-    s = jnp.max(jnp.abs(acc)) / 127.0
-    s = jnp.where(s > 0, s, 1.0)
-    qout_ref[...] = jnp.round(acc / s).astype(jnp.int8)
-    sout_ref[0] = s
+    """Quantize an ``(R, LANES)`` f32 message to ``(q int8 (R, LANES),
+    scale f32 (1,))`` with a symmetric per-message max-abs scale
+    (``kernels.ref.int8_quantize_ref`` is the oracle; zero pad quantizes to
+    zero and leaves the scale alone).  Used for the FIRST send of the int8
+    ring — every later hop re-quantizes inside ``ring_hop_int8``."""
+    return _int8_requant(x[None], 0, interpret=interpret)
 
 
 def ring_hop_int8(chunks: jax.Array, q: jax.Array, scale: jax.Array,
@@ -242,59 +337,23 @@ def ring_hop_int8(chunks: jax.Array, q: jax.Array, scale: jax.Array,
     """One int8 ring hop, fully fused: dequantize the received message
     ``(q, scale)``, add this member's local partial of chunk ``c`` in f32,
     re-quantize against a fresh max-abs scale.  Returns the next wire
-    message ``(q' int8 (n,), scale' f32 (1,))``.  Same scalar-prefetch
-    chunk selection as :func:`ring_hop_accum`."""
-    from jax.experimental.pallas import tpu as pltpu
-    G, n = chunks.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, n), lambda i, c_ref: (c_ref[0], 0)),
-                  pl.BlockSpec((n,), lambda i, c_ref: (0,)),
-                  pl.BlockSpec((1,), lambda i, c_ref: (0,))],
-        out_specs=[pl.BlockSpec((n,), lambda i, c_ref: (0,)),
-                   pl.BlockSpec((1,), lambda i, c_ref: (0,))],
-    )
-    return pl.pallas_call(
-        _hop_int8_kernel,
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((n,), jnp.int8),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)),
-        interpret=_auto_interpret(interpret),
-    )(jnp.asarray(c, jnp.int32).reshape(1), chunks, q, scale)
-
-
-def _hop_topk_kernel(c_ref, chunk_ref, val_ref, idx_ref, out_ref):
-    del c_ref
-    n = out_ref.shape[0]
-    dense = jnp.zeros((n,), jnp.float32).at[idx_ref[...]].add(
-        val_ref[...].astype(jnp.float32))
-    out_ref[...] = dense + chunk_ref[0].astype(jnp.float32)
+    message ``(q' int8 (R, LANES), scale' f32 (1,))``.  Same tile layout and
+    scalar-prefetch chunk selection as :func:`ring_hop_accum`."""
+    return _int8_requant(chunks, c, q, scale, interpret=interpret)
 
 
 def ring_hop_topk(chunks: jax.Array, vals: jax.Array, idx: jax.Array,
                   c: jax.Array, *,
                   interpret: Optional[bool] = None) -> jax.Array:
     """One top-k ring hop combine: scatter-add the received sparse message
-    ``(vals, idx)`` into a dense f32 buffer and add this member's local
-    partial of chunk ``c``.  Returns the dense ``(n,)`` accumulator — the
+    ``(vals, idx)`` (indices into the flattened tiles) into a dense f32
+    ``(R, LANES)`` buffer and add this member's local partial of chunk
+    ``c``.  The scatter is XLA's — a data-dependent scatter has no tiled
+    Mosaic form — and the add is the :func:`ring_hop_accum` kernel.  The
     backend re-selects its top-k before forwarding (and keeps the dense
     result on the final hop, so the LAST combine loses nothing)."""
-    from jax.experimental.pallas import tpu as pltpu
-    G, n = chunks.shape
-    k, = vals.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, n), lambda i, c_ref: (c_ref[0], 0)),
-                  pl.BlockSpec((k,), lambda i, c_ref: (0,)),
-                  pl.BlockSpec((k,), lambda i, c_ref: (0,))],
-        out_specs=pl.BlockSpec((n,), lambda i, c_ref: (0,)),
-    )
-    return pl.pallas_call(
-        _hop_topk_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=_auto_interpret(interpret),
-    )(jnp.asarray(c, jnp.int32).reshape(1), chunks, vals,
-      jnp.asarray(idx, jnp.int32))
+    R = chunks.shape[1]
+    dense = jnp.zeros((R * LANES,), jnp.float32).at[idx].add(
+        vals.astype(jnp.float32))
+    return ring_hop_accum(chunks, dense.reshape(R, LANES), c,
+                          interpret=interpret)

@@ -57,8 +57,10 @@ def worker_main(args) -> int:
     import jax
 
     from repro.api import compile_run
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.train import spec_from_args
 
+    use_compile_cache()
     run = compile_run(spec_from_args(args, cluster=True))
     if jax.process_index() == 0:
         print(f"cluster: {spec.num_processes} processes x "
@@ -88,11 +90,22 @@ def worker_main(args) -> int:
 
 def _verify_single(args) -> float:
     """The same run, single-process, fresh state (no resume): the
-    G-invariance reference the cluster's final loss must match."""
+    G-invariance reference the cluster's final loss must match, on the
+    workers' platform."""
+    import dataclasses
+
+    import jax
+
     from repro.api import compile_run
+    from repro.cluster.launcher import WORKER_PLATFORM
     from repro.launch.train import spec_from_args
 
-    import dataclasses
+    jax.config.update("jax_platforms", WORKER_PLATFORM)
+    if jax.devices()[0].platform != WORKER_PLATFORM:
+        raise RuntimeError(
+            f"--verify reference must run on {WORKER_PLATFORM} like the "
+            f"workers, but this process already uses "
+            f"{jax.devices()[0].platform}")
     spec = spec_from_args(args, cluster=False)
     # telemetry stripped: the supervisor has no REPRO_PROCESS_ID, so its
     # trace_p0.jsonl would collide with worker 0's

@@ -59,16 +59,8 @@ def model_flops(cfg: ModelConfig, kind: str, batch: int, seq: int) -> float:
     return 2.0 * n * batch  # decode: one token per request
 
 
-def _cost_dict(cost):
-    """jax 0.4.x returns cost_analysis() as a one-element list of dicts."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def _combine(full_cost, unit_cost, full_hlo, unit_hlo, repeats: int):
     """XLA counts a while-loop body once; totals = full + (R-1) * unit."""
-    full_cost, unit_cost = _cost_dict(full_cost), _cost_dict(unit_cost)
     r = repeats - 1
     flops = full_cost.get("flops", 0.0) + r * unit_cost.get("flops", 0.0)
     nbytes = full_cost.get("bytes accessed", 0.0) \

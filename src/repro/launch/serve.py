@@ -17,6 +17,7 @@ import numpy as np
 from repro.api import ServeSpec, compile_serve
 from repro.api.spec import PAGED_ATTN_IMPLS, SCHEDULER_POLICIES
 from repro.configs import ASSIGNED_ARCHS
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -44,6 +45,7 @@ def main(argv=None):
                      max_new_tokens=args.new, scheduler=args.scheduler,
                      attn_impl=args.attn_impl, temperature=args.temperature,
                      seed=args.seed)
+    use_compile_cache()
     server = compile_serve(spec)
 
     rng = np.random.default_rng(args.seed)

@@ -27,9 +27,9 @@ A ``--ckpt-dir`` run periodically checkpoints AND auto-resumes: relaunching
 the same command picks up from the latest saved step (params, optimizer
 strips and data-stream position), not from step 0.
 
-On CPU (this container) use --smoke for the reduced config; on a real TPU
-slice the full config shards across the detected devices with the same
-rules/plan machinery the dry-run exercises."""
+Without --smoke the config is the published full width; on a TPU host it
+shards across the detected devices (``chip_smoke.py`` at the repo root
+drives full-width vgg-a this way)."""
 from __future__ import annotations
 
 import argparse
@@ -45,6 +45,7 @@ from repro.api import (
 )
 from repro.comm import COLLECTIVE_BACKENDS, WIRE_FORMATS, CommConfig
 from repro.configs import ALL_ARCHS
+from repro.launch.compile_cache import use_compile_cache
 
 WIRE_DTYPES = {"fp32": "float32", "bf16": "bfloat16"}
 
@@ -96,7 +97,8 @@ def spec_from_args(args, cluster: bool = False) -> RunSpec:
         comm=comm, optimizer=args.optimizer, lr=args.lr,
         schedule=args.schedule,
         steps=args.steps, batch=args.batch, seq=args.seq, seed=args.seed,
-        log_every=5, ckpt_every=ckpt_every, ckpt_dir=args.ckpt_dir,
+        log_every=args.log_every, ckpt_every=ckpt_every,
+        ckpt_dir=args.ckpt_dir,
         telemetry=getattr(args, "trace_dir", None))
 
 
@@ -169,6 +171,9 @@ def add_run_args(ap: argparse.ArgumentParser, parallel_default: str = "dp"):
                     choices=["adamw", "sgd"],
                     help="default: family choice (momentum SGD for the "
                          "paper's CNN/DNN, AdamW for transformers)")
+    ap.add_argument("--log-every", type=int, default=5,
+                    help="log (and record in the history) every N steps; "
+                         "the first and last steps always log")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0,
                     help="checkpoint period in steps (default: steps/5 "
@@ -226,6 +231,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     check_run_args(ap, args)
 
+    use_compile_cache()
     run = compile_run(spec_from_args(args))
     # report the RESOLVED comm plan (run.comm), not spec.comm — the spec may
     # say the string "auto", the run carries what the autotuner picked

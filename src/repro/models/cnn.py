@@ -33,9 +33,11 @@ def param_specs(cfg: CNNConfig) -> Dict[str, Spec]:
     sp: Dict[str, Spec] = {}
     for i, lyr in enumerate(cfg.layers):
         if lyr.kind == "conv":
+            # fan-in is kernel*kernel*ifm; init_tree's fan_in rule divides
+            # by sqrt(ifm) (the (.., in, out) axis), so scale by 1/kernel
             sp[_key("conv", i, "w")] = Spec(
                 (lyr.kernel, lyr.kernel, lyr.ifm, lyr.ofm),
-                ("kernel", "kernel", "embed", "ff"))
+                ("kernel", "kernel", "embed", "ff"), scale=1.0 / lyr.kernel)
             sp[_key("conv", i, "b")] = Spec((lyr.ofm,), ("ff",),
                                             init="zeros")
         elif lyr.kind == "fc":

@@ -184,6 +184,23 @@ def _roundtrip_fn(mesh, axis_arg, base: CommConfig, backend: str, G: int):
                                  out_specs=P(), check_vma=False))
 
 
+def _rejection(mesh, axis_arg, base: CommConfig, backend: str, G: int,
+               sizes: Sequence[int]) -> Optional[Exception]:
+    """The ``ValueError`` / ``NotImplementedError`` with which a backend's
+    own validation rejects this mesh or these buffer sizes, else None.
+    Tracing the roundtrip runs that validation and compiles nothing; a
+    compile or runtime failure later, in the timed probe, propagates — a
+    kernel the device refuses must never turn into "auto chose lax"."""
+    fn = _roundtrip_fn(mesh, axis_arg, base, backend, G)
+    try:
+        with jax.set_mesh(mesh):
+            for n in sizes:
+                fn.trace(jax.ShapeDtypeStruct((int(n),), base.wire_dtype))
+    except (ValueError, NotImplementedError) as e:
+        return e
+    return None
+
+
 def _time_backend(mesh, axis_arg, base: CommConfig, backend: str, G: int,
                   sizes: Sequence[int], reps: int, recorder,
                   clock=time.perf_counter) -> List[CommProbe]:
@@ -234,9 +251,11 @@ def autotune_comm(params, mesh, data_axes, base: CommConfig,
 
     ``backends`` is the candidate set (the mode's ``MODE_CAPS.backends``);
     ``base.backend`` is always probed first and is the fallback when an
-    alternative fails to build or run on this mesh.  ``wire_formats`` is
-    the mode's wire-format capability set; ``topk`` is filtered out (lossy
-    AND stateful — explicit opt-in only, see module docstring).
+    alternative's validation rejects this mesh (``ValueError`` /
+    ``NotImplementedError``); any other probe failure is raised.
+    ``wire_formats`` is the mode's wire-format capability set; ``topk`` is
+    filtered out (lossy AND stateful — explicit opt-in only, see module
+    docstring).
     ``cache_path`` short-circuits the probe when a persisted plan's key
     matches this launch."""
     from repro.telemetry.events import NULL_RECORDER
@@ -274,16 +293,14 @@ def autotune_comm(params, mesh, data_axes, base: CommConfig,
     fits = {}
     all_probes: List[CommProbe] = []
     for backend in candidates:
-        try:
-            probes = _sync_times(_time_backend(
-                mesh, axis_arg, base, backend, G, sizes, reps, recorder))
-        except Exception as e:  # an alt backend that can't run here is
-            #                     skipped, not fatal — base always works
-            if backend == base.backend:
-                raise
-            log(f"comm=auto: backend {backend!r} probe failed "
-                f"({type(e).__name__}: {e}); skipping")
+        rejected = (None if backend == base.backend else
+                    _rejection(mesh, axis_arg, base, backend, G, sizes))
+        if rejected is not None:
+            log(f"comm=auto: backend {backend!r} rejected this run "
+                f"({type(rejected).__name__}: {rejected}); skipping")
             continue
+        probes = _sync_times(_time_backend(
+            mesh, axis_arg, base, backend, G, sizes, reps, recorder))
         all_probes.extend(probes)
         fits[backend] = fit_comm_model(probes, G)
 

@@ -121,8 +121,11 @@ class Trainer:
                 log_fn(f"step {step + 1:5d}  loss {loss:8.4f}  "
                        f"gnorm {float(metrics['grad_norm']):7.3f}  "
                        f"lr {float(metrics['lr']):.2e}  {tail}")
-                history.append(dict(step=step + 1, loss=loss,
-                                    grad_norm=float(metrics["grad_norm"])))
+                entry = dict(step=step + 1, loss=loss,
+                             grad_norm=float(metrics["grad_norm"]))
+                if first:
+                    entry["compile_s"] = t_compile
+                history.append(entry)
             if (self.cfg.ckpt_every and self.cfg.ckpt_dir
                     and (step + 1) % self.cfg.ckpt_every == 0):
                 with rec.span("ckpt_write", step=step + 1):

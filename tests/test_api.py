@@ -439,10 +439,11 @@ def test_api_stale_sync_and_gossip_converge_vs_serial():
             h = r.fit(log_fn=quiet); r.close()
             return [float(x["loss"]) for x in h]
 
-        # VGG-A: all three modes must actually train
-        serial = fit("vgg-a", "serial", 12, 5e-3)
-        gossip = fit("vgg-a", "gossip", 12, 5e-3)
-        stale = fit("vgg-a", "stale-sync", 12, 5e-3)
+        # VGG-A: all three modes must actually train (from a fan-in init
+        # at loss ~ln 16, halving the loss takes ~40 steps at 1e-2)
+        serial = fit("vgg-a", "serial", 40, 1e-2)
+        gossip = fit("vgg-a", "gossip", 40, 1e-2)
+        stale = fit("vgg-a", "stale-sync", 40, 1e-2)
         np.testing.assert_allclose(gossip, serial, rtol=1e-4)
         assert serial[-1] < 0.5 * serial[0], serial
         assert stale[-1] < 0.5 * stale[0], stale

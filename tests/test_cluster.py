@@ -25,9 +25,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 300) -> str:
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
-    prelude = "import repro.jaxcompat\n"
-    out = subprocess.run([sys.executable, "-c",
-                          prelude + textwrap.dedent(code)],
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          env=env, capture_output=True, text=True,
                          timeout=timeout)
     assert out.returncode == 0, out.stderr[-4000:]

@@ -14,11 +14,7 @@ def run_py(code: str, devices: int = 8) -> str:
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
-    # the snippets touch jax.sharding before importing repro, so load the
-    # 0.4.x API backfill first (a no-op on jax that has the real APIs)
-    prelude = "import repro.jaxcompat\n"
-    out = subprocess.run([sys.executable, "-c",
-                          prelude + textwrap.dedent(code)],
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          env=env, capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-4000:]
     return out.stdout

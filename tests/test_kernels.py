@@ -199,12 +199,13 @@ def test_ring_reduce_scatter_rejects_ragged_buffer():
 def test_ring_hop_accum_matches_jnp(dtype):
     """The distributed backend's per-hop combine: recv + chunks[c] for every
     valid (traced) chunk index."""
-    from repro.kernels.ring import ring_hop_accum
+    from repro.kernels.ring import from_tiles, ring_hop_accum, to_tiles
     G, n = 4, 24
     chunks = _arr(G, n, dtype=dtype)
     recv = _arr(n, dtype=dtype)
     for c in range(G):
-        got = ring_hop_accum(chunks, recv, jnp.int32(c), interpret=True)
+        got = from_tiles(ring_hop_accum(to_tiles(chunks), to_tiles(recv),
+                                        jnp.int32(c), interpret=True), n)
         np.testing.assert_allclose(
             np.asarray(got, np.float32),
             np.asarray(recv + chunks[c], np.float32), rtol=1e-6, atol=1e-6)

@@ -6,6 +6,7 @@ table, end-to-end loss-equal in a subprocess), the heartbeat redesign
 Forced-device-count runs go through subprocesses (same isolation policy as
 tests/test_cluster.py) so the rest of the suite keeps the single real CPU
 device."""
+import functools
 import json
 import os
 import subprocess
@@ -27,9 +28,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 420,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
     env.update(extra_env or {})
-    prelude = "import repro.jaxcompat\n"
-    out = subprocess.run([sys.executable, "-c",
-                          prelude + textwrap.dedent(code)],
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          env=env, capture_output=True, text=True,
                          timeout=timeout)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -244,6 +243,41 @@ def test_autotune_picks_measured_optimal_bucket_on_mesh():
         print("OK bucket", comm.bucket_bytes, "backend", comm.backend)
     """, devices=8)
     assert "OK" in out
+
+
+@pytest.mark.parametrize("exc,skipped", [(NotImplementedError, True),
+                                         (ValueError, True),
+                                         (RuntimeError, False)])
+def test_autotune_skips_only_validation_rejections(monkeypatch, exc,
+                                                   skipped):
+    """An alternative backend whose validation rejects the run is skipped;
+    any other failure (a kernel the device's compiler refuses) propagates
+    instead of quietly leaving the base backend chosen."""
+    import jax.numpy as jnp
+
+    from repro.comm import CommConfig, backends
+    from repro.launch.mesh import make_host_mesh
+    from repro.telemetry.autotune import autotune_comm
+
+    class Refusing:
+        def part_reduce(self, x, axis_name, dim=0):
+            raise exc("refused")
+
+        def part_broadcast(self, x, axis_name, dim=0):
+            return x
+
+    monkeypatch.setitem(backends._FACTORIES, "pallas-ring", Refusing)
+    run = functools.partial(
+        autotune_comm, {"w": jnp.zeros((4096,), jnp.float32)},
+        make_host_mesh(1), ("data",), CommConfig(),
+        backends=("lax", "pallas-ring"), reps=1)
+    if skipped:
+        logs = []
+        assert run(log=logs.append).backend == "lax"
+        assert any("'pallas-ring' rejected" in ln for ln in logs), logs
+    else:
+        with pytest.raises(exc, match="refused"):
+            run(log=lambda *_: None)
 
 
 def test_comm_auto_run_matches_fixed_comm_loss_and_emits_trace():

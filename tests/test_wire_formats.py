@@ -39,9 +39,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 300) -> str:
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
-    prelude = "import repro.jaxcompat\n"
-    out = subprocess.run([sys.executable, "-c",
-                          prelude + textwrap.dedent(code)],
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          env=env, capture_output=True, text=True,
                          timeout=timeout)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -55,7 +53,8 @@ def run_py(code: str, devices: int = 8, timeout: int = 300) -> str:
 @pytest.mark.parametrize("n", [1, 5, 128, 1000, 4096])
 def test_int8_quantize_matches_oracle_exactly(n):
     x = _arr(n)
-    q, s = kring.int8_quantize(x, interpret=True)
+    q, s = kring.int8_quantize(kring.to_tiles(x), interpret=True)
+    q = kring.from_tiles(q, n)
     qr, sr = kref.int8_quantize_ref(x)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-7)
@@ -63,7 +62,8 @@ def test_int8_quantize_matches_oracle_exactly(n):
 
 
 def test_int8_quantize_all_zero_message_is_well_defined():
-    q, s = kring.int8_quantize(jnp.zeros((64,), jnp.float32), interpret=True)
+    q, s = kring.int8_quantize(kring.to_tiles(jnp.zeros((64,), jnp.float32)),
+                               interpret=True)
     assert float(s[0]) == 1.0     # scale 1.0 so dequantize is a no-op
     assert not np.asarray(q).any()
 
@@ -84,10 +84,12 @@ def test_ring_hop_int8_matches_oracle(G, n):
     chunks = _arr(G, n)
     q, s = kref.int8_quantize_ref(_arr(n))
     for c in range(G):
-        qk, sk = kring.ring_hop_int8(chunks, q, s, jnp.int32(c),
+        qk, sk = kring.ring_hop_int8(kring.to_tiles(chunks),
+                                     kring.to_tiles(q), s, jnp.int32(c),
                                      interpret=True)
         qr, sr = kref.ring_hop_int8_ref(chunks, q, s, c)
-        np.testing.assert_array_equal(np.asarray(qk), np.asarray(qr))
+        np.testing.assert_array_equal(np.asarray(kring.from_tiles(qk, n)),
+                                      np.asarray(qr))
         np.testing.assert_allclose(np.asarray(sk), np.asarray(sr),
                                    rtol=1e-6)
 
@@ -119,8 +121,9 @@ def test_ring_hop_topk_matches_oracle(G, n, k):
     chunks = _arr(G, n)
     vals, idx = kref.topk_select_ref(_arr(n), k)
     for c in range(G):
-        got = kring.ring_hop_topk(chunks, vals, idx, jnp.int32(c),
-                                  interpret=True)
+        got = kring.from_tiles(
+            kring.ring_hop_topk(kring.to_tiles(chunks), vals, idx,
+                                jnp.int32(c), interpret=True), n)
         want = kref.ring_hop_topk_ref(chunks, vals, idx, c)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)
