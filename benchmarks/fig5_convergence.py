@@ -387,8 +387,11 @@ def report() -> dict:
     compressed-wire convergence gates CI asserts."""
     rws = rows()
     d = {name: {"value": v, "ref": ref} for name, v, ref in rws}
+    dev = jax.devices()[0]
     return {
         "benchmark": "fig5_convergence",
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": jax.device_count()},
         "rows": d,
         "gates": {
             "int8_within_tol":

@@ -29,13 +29,16 @@ Resolution order:
    grads -> update into the jit-ready step the returned
    :class:`~repro.api.run.Run` carries.
 
+Steps 4-5 are :func:`assemble_step`, which needs the params' shapes and
+shardings but not their values (except for ``comm="auto"``).
+
 ROADMAP follow-ons (async modes, multi-backend collectives) plug in at
 step 4 without touching any launcher — the bucket-autotuning hook already
 does (``comm="auto"``).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh
@@ -87,13 +90,14 @@ def _make_schedule(spec: RunSpec, data_ways: int = 1):
     return warmup_cosine(spec.lr, warmup, spec.steps)
 
 
-def _place_params(params, family: FamilyAdapter, cfg, mesh: Mesh,
-                  rules: ShardingRules):
-    shardings = jax.tree.map(
+def param_shardings(family: FamilyAdapter, cfg, mesh: Mesh,
+                    rules: ShardingRules):
+    """Each parameter's sharding on ``mesh`` under the logical-axis
+    ``rules``."""
+    return jax.tree.map(
         lambda s: rules.sharding(s.axes, s.shape, mesh),
         family.param_specs(cfg),
         is_leaf=lambda x: isinstance(x, Spec))
-    return jax.tree.map(jax.device_put, params, shardings)
 
 
 def _data_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -101,30 +105,30 @@ def _data_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def compile_run(spec: RunSpec, rules: Optional[ShardingRules] = None) -> Run:
-    """Assemble a ready-to-train :class:`Run` from a declarative ``spec``.
+class StepParts(NamedTuple):
+    """What :func:`assemble_step` builds around the params."""
+    ctx: ShardingCtx
+    loss_fn: Callable
+    optimizer: Any
+    lr_schedule: Callable
+    comm: Optional[CommConfig]      # resolved; None outside the bucketed modes
+    init_fn: Callable               # params -> placed optimizer state
+    train_step: Callable            # (params, opt_state, step, batch) -> ...
 
-    ``rules`` overrides the logical-axis sharding rule table (defaults to
-    the paper-faithful hybrid-parallel rules).
+
+def assemble_step(spec: RunSpec, cfg, family: FamilyAdapter,
+                  mesh: Optional[Mesh], rules: ShardingRules, params,
+                  telemetry) -> StepParts:
+    """Steps 4-5 of the resolution order: the loss, optimizer, schedule,
+    update path and train step of ``spec`` on ``mesh``.
+
+    ``params`` are read only by ``comm="auto"``, which times collectives on
+    the live arrays; otherwise they may be ``jax.ShapeDtypeStruct`` leaves,
+    so the step ``compile_run`` trains can be lowered for devices that are
+    described rather than attached.
     """
-    cfg = _resolve_config(spec)
-    family = adapter_for(cfg)
-    telemetry = make_recorder(spec.telemetry)
-
-    mesh = None
-    if spec.parallel != "serial":
-        if spec.mesh.cluster:
-            mesh = make_cluster_mesh(spec.mesh.model_ways)
-        else:
-            mesh = make_host_mesh(spec.mesh.model_ways, pods=spec.mesh.pods)
-    rules = rules if rules is not None else ShardingRules()
     ctx = ShardingCtx(mesh, rules)
     loss_fn = family.make_loss(cfg, ctx)
-
-    params = family.init(cfg, jax.random.PRNGKey(spec.seed))
-    if mesh is not None:
-        params = _place_params(params, family, cfg, mesh, rules)
-
     optimizer = _make_optimizer(spec, family)
     data_ways = 1
     if mesh is not None:
@@ -135,6 +139,7 @@ def compile_run(spec: RunSpec, rules: Optional[ShardingRules] = None) -> Run:
     dist_update = None
     train_step = None
     comm = None
+    init_fn = optimizer.init
     if spec.parallel in ("zero1", "stale-sync", "gossip"):
         axes = _data_axes(mesh)
         if spec.parallel == "gossip":
@@ -165,14 +170,12 @@ def compile_run(spec: RunSpec, rules: Optional[ShardingRules] = None) -> Run:
         if spec.parallel == "stale-sync":
             init_fn, dist_update = make_stale_sync_update(
                 optimizer, mesh, data_axes=axes, comm=comm)
-            opt_state = init_fn(params)
         elif comm.wire_format == "topk":
             # spec validation pinned this to the monolithic zero1 pipeline
             # (no overlap, no stale-sync, no gossip): the error-feedback
             # residual needs the strip-state carry of the EF composition
             init_fn, dist_update = make_topk_ef_update(
                 optimizer, mesh, data_axes=axes, comm=comm)
-            opt_state = init_fn(params)
         elif comm.overlap:
             # §3.1 bubble schedule: the whole step runs in one shard_map and
             # each bucket's part-reduce is issued inside the backward pass
@@ -188,30 +191,57 @@ def compile_run(spec: RunSpec, rules: Optional[ShardingRules] = None) -> Run:
                     f"(got model_ways={spec.mesh.model_ways})")
             init_fn, local_update = make_overlapped_update(
                 optimizer, mesh, data_axes=axes, comm=comm)
-            opt_state = init_fn(params)
             train_step = make_overlapped_train_step(
                 family.make_loss(cfg, ShardingCtx()), lr_schedule, mesh,
                 axes, comm, local_update, grad_clip=spec.grad_clip)
         else:
             init_fn, dist_update = make_distributed_update(
                 optimizer, mesh, data_axes=axes, comm=comm)
-            opt_state = init_fn(params)
     elif spec.parallel == "zero1-gspmd":
-        opt_state = optimizer.init(params)
-        st_sh = zero1_state_shardings(opt_state, family.param_axes(cfg),
-                                      mesh, rules)
-        opt_state = jax.tree.map(jax.device_put, opt_state, st_sh)
-    else:
-        opt_state = optimizer.init(params)
+        def init_fn(params):
+            opt_state = optimizer.init(params)
+            st_sh = zero1_state_shardings(opt_state, family.param_axes(cfg),
+                                          mesh, rules)
+            return jax.tree.map(jax.device_put, opt_state, st_sh)
 
     if train_step is None:
         train_step = make_train_step(loss_fn, optimizer, lr_schedule,
                                      grad_clip=spec.grad_clip,
                                      dist_update=dist_update)
+    return StepParts(ctx=ctx, loss_fn=loss_fn, optimizer=optimizer,
+                     lr_schedule=lr_schedule, comm=comm, init_fn=init_fn,
+                     train_step=train_step)
+
+
+def compile_run(spec: RunSpec, rules: Optional[ShardingRules] = None) -> Run:
+    """Assemble a ready-to-train :class:`Run` from a declarative ``spec``.
+
+    ``rules`` overrides the logical-axis sharding rule table (defaults to
+    the paper-faithful hybrid-parallel rules).
+    """
+    cfg = _resolve_config(spec)
+    family = adapter_for(cfg)
+    telemetry = make_recorder(spec.telemetry)
+
+    mesh = None
+    if spec.parallel != "serial":
+        if spec.mesh.cluster:
+            mesh = make_cluster_mesh(spec.mesh.model_ways)
+        else:
+            mesh = make_host_mesh(spec.mesh.model_ways, pods=spec.mesh.pods)
+    rules = rules if rules is not None else ShardingRules()
+
+    params = family.init(cfg, jax.random.PRNGKey(spec.seed))
+    if mesh is not None:
+        params = jax.tree.map(jax.device_put, params,
+                              param_shardings(family, cfg, mesh, rules))
+
+    parts = assemble_step(spec, cfg, family, mesh, rules, params, telemetry)
     return Run(spec=spec, cfg=cfg, family=family, mesh=mesh, rules=rules,
-               ctx=ctx, loss_fn=loss_fn, optimizer=optimizer,
-               lr_schedule=lr_schedule, train_step=train_step,
-               params=params, opt_state=opt_state, comm=comm,
+               ctx=parts.ctx, loss_fn=parts.loss_fn,
+               optimizer=parts.optimizer, lr_schedule=parts.lr_schedule,
+               train_step=parts.train_step, params=params,
+               opt_state=parts.init_fn(params), comm=parts.comm,
                telemetry=telemetry)
 
 

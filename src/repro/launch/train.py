@@ -225,14 +225,19 @@ def check_run_args(ap: argparse.ArgumentParser, args) -> None:
                  "error-feedback residual has nowhere to live")
 
 
-def main(argv=None):
+def parse_run_spec(argv=None) -> RunSpec:
+    """The ``RunSpec`` this launcher builds from ``argv``."""
     ap = argparse.ArgumentParser()
     add_run_args(ap)
     args = ap.parse_args(argv)
     check_run_args(ap, args)
+    return spec_from_args(args)
 
+
+def main(argv=None):
+    spec = parse_run_spec(argv)
     use_compile_cache()
-    run = compile_run(spec_from_args(args))
+    run = compile_run(spec)
     # report the RESOLVED comm plan (run.comm), not spec.comm — the spec may
     # say the string "auto", the run carries what the autotuner picked
     print(f"arch: {run.cfg.name}  family={run.family.family}  "

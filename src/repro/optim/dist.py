@@ -143,7 +143,9 @@ class UpdatePlan:
     def init_fn(self, params):
         """(G, n/G) fusion-buffer strip state placed on the mesh — shared
         by every mode (all consume the same plan and owner layout, so a
-        checkpoint written by one path restores into another)."""
+        checkpoint written by one path restores into another).  Traceable:
+        ``jax.eval_shape`` gives the placed state's shapes from param
+        shapes."""
         perm = self.owner_layout()
 
         def _strip_init(params):
@@ -156,14 +158,10 @@ class UpdatePlan:
                 strips = [s[perm] for s in strips]
             return self.optimizer.init(strips)
 
-        # compute replicated, then reshard with device_put: jit with
-        # out_shardings miscompiles this pack+reshard pattern on jax 0.4.x
-        # (values arrive multiplied by a mesh-axis extent)
-        with jax.set_mesh(self.mesh):
-            state = jax.jit(_strip_init)(params)
         shardings = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, self.state_spec(s)), state)
-        return jax.tree.map(jax.device_put, state, shardings)
+            lambda s: NamedSharding(self.mesh, self.state_spec(s)),
+            jax.eval_shape(_strip_init, params))
+        return jax.jit(_strip_init, out_shardings=shardings)(params)
 
     # -- the three phases (called INSIDE shard_map) --------------------
     def reduce(self, sched: Schedule, plan: BucketPlan, grads):

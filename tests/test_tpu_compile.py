@@ -12,13 +12,16 @@ is explicit everywhere, because the kernels' auto-selection sees the CPU.
 """
 import functools
 import math
+import os
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
+
+import chip_smoke
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 MIB = 2**20
@@ -27,6 +30,8 @@ MIB = 2**20
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
+    # before the TPU library loads: its logs go nowhere, not to /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -98,48 +103,31 @@ def test_paged_decode_attention_compiles(one_chip):
 # ---------------------------------------------------------------------------
 # whole full-width vgg-a zero1 train steps
 # ---------------------------------------------------------------------------
-def _vgg_a_zero1_step(devices, batch: int, backend: str):
-    """The train step ``compile_run`` assembles for vgg-a zero1, built on a
-    mesh of described devices and lowered from shapes."""
-    from repro.api.families import CNN_FAMILY
-    from repro.comm.bucketer import CommConfig, plan_buckets
+def _train_step(devices, argv):
+    """The train step ``compile_run`` assembles for the train CLI's
+    ``argv``, on a (len(devices), 1) data mesh of described devices,
+    lowered from shapes."""
+    from repro.api.assemble import assemble_step, param_shardings
+    from repro.api.families import adapter_for
     from repro.configs import get_config
-    from repro.core.params import Spec
-    from repro.core.sharding import ShardingCtx, ShardingRules
+    from repro.core.sharding import ShardingRules
     from repro.data.pipeline import BATCH_SPECS
-    from repro.optim import MomentumSGD, constant
-    from repro.optim.dist import make_distributed_update
-    from repro.train import make_train_step
+    from repro.launch.train import parse_run_spec
+    from repro.telemetry import make_recorder
 
-    cfg = get_config("vgg-a")
+    spec = parse_run_spec(argv)
+    cfg = get_config(spec.arch)
+    family = adapter_for(cfg)
     mesh = Mesh(np.array(devices).reshape(len(devices), 1), ("data", "model"))
     rules = ShardingRules()
-    opt = MomentumSGD()
-    comm = CommConfig(backend=backend)
-    _, update = make_distributed_update(opt, mesh, data_axes=("data",),
-                                        comm=comm)
-    step = make_train_step(CNN_FAMILY.make_loss(cfg, ShardingCtx(mesh, rules)),
-                           opt, constant(1e-3), dist_update=update)
-
-    def placed(shapes, shardings):
-        return jax.tree.map(
-            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-            shapes, shardings)
-
-    p_shapes = jax.eval_shape(functools.partial(CNN_FAMILY.init, cfg),
-                              jax.random.PRNGKey(0))
-    params = placed(p_shapes, jax.tree.map(
-        lambda s: rules.sharding(s.axes, s.shape, mesh),
-        CNN_FAMILY.param_specs(cfg), is_leaf=lambda x: isinstance(x, Spec)))
-    # the strip state init_fn builds: one (G, n/G) row block per bucket
-    G = len(devices)
-    s_shapes = jax.eval_shape(opt.init, [
-        jax.ShapeDtypeStruct((G, b.padded_size // G), jnp.float32)
-        for b in plan_buckets(p_shapes, G, comm.bucket_bytes).buckets])
-    state = placed(s_shapes, jax.tree.map(
-        lambda s: NamedSharding(mesh, P("data") if s.ndim >= 2 else P()),
-        s_shapes))
-    hw = cfg.image_size
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(functools.partial(family.init, cfg),
+                       jax.random.PRNGKey(spec.seed)),
+        param_shardings(family, cfg, mesh, rules))
+    parts = assemble_step(spec, cfg, family, mesh, rules, params,
+                          make_recorder(None))
+    hw, batch = cfg.image_size, spec.batch
     batch_sds = {
         "images": jax.ShapeDtypeStruct(
             (batch, hw, hw, 3), jnp.float32,
@@ -149,9 +137,9 @@ def _vgg_a_zero1_step(devices, batch: int, backend: str):
             (batch,), jnp.int32,
             sharding=rules.sharding(BATCH_SPECS["labels"], (batch,), mesh))}
     with jax.set_mesh(mesh):
-        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        state = jax.eval_shape(parts.init_fn, params)
+        return jax.jit(parts.train_step, donate_argnums=(0, 1)).lower(
             params, state, 0, batch_sds).compile()
-    return compiled
 
 
 def _device_bytes(compiled) -> int:
@@ -161,7 +149,8 @@ def _device_bytes(compiled) -> int:
 
 
 def test_vgg_a_zero1_step_fits_one_chip(topo):
-    compiled = _vgg_a_zero1_step(topo.devices[:1], batch=64, backend="lax")
+    # the step chip_smoke.py trains on one chip
+    compiled = _train_step(topo.devices[:1], chip_smoke.ONE_CHIP_ARGV)
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
 
@@ -172,7 +161,9 @@ def test_vgg_a_zero1_pallas_ring_step_compiles_on_four_chips(topo,
     # the backend resolves by name and would pick interpret mode on this CPU
     monkeypatch.setitem(backends._FACTORIES, "pallas-ring",
                         functools.partial(PallasRingBackend, interpret=False))
-    compiled = _vgg_a_zero1_step(topo.devices[:4], batch=128,
-                                 backend="pallas-ring")
+    # the pallas-ring step chip_smoke.py --chips 4 trains
+    compiled = _train_step(
+        topo.devices[:4], chip_smoke.FOUR_CHIP_ARGV + ["--parallel", "zero1"]
+        + chip_smoke.FOUR_CHIP_BACKENDS["pallas-ring"])
     assert "tpu_custom_call" in compiled.as_text()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
