@@ -21,7 +21,8 @@ from jax.sharding import Mesh
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.core.sharding import ShardingCtx, ShardingRules
 from repro.data.pipeline import Prefetcher, make_placer
-from repro.train.trainer import Trainer, TrainerConfig
+from repro.telemetry.events import process_recorder
+from repro.train.trainer import Trainer, TrainerConfig, dispatch_step
 
 
 @dataclass
@@ -44,16 +45,24 @@ class Run:
     #                                 bucketed run (zero1/stale-sync/gossip;
     #                                 None for other modes) — needed to
     #                                 re-plan strip state across world sizes
-    telemetry: Optional[Any] = None  # the run's telemetry Recorder
-    #                                 (repro.telemetry): trainer phases are
-    #                                 spans, listeners (cluster heartbeat,
-    #                                 sinks) ride its events; ``close``
-    #                                 finalizes it and exports the Chrome
-    #                                 trace when the spec set a trace_dir.
-    #                                 None (hand-built Runs) = no-op.
+    telemetry: Optional[Any] = None  # the run's handle on the process
+    #                                 recorder (repro.telemetry
+    #                                 .make_recorder): step and data
+    #                                 phases are spans, listeners (cluster
+    #                                 heartbeat, sinks) ride its events;
+    #                                 ``close`` detaches the run's sinks
+    #                                 and exports the Chrome trace when the
+    #                                 spec set a trace_dir.  None
+    #                                 (hand-built Runs) = the process
+    #                                 recorder itself.
     _data: Optional[Prefetcher] = field(default=None, repr=False)
     _jit_step: Optional[Callable] = field(default=None, repr=False)
     _warm: bool = field(default=False, repr=False)  # jit_step executed once
+
+    @property
+    def recorder(self):
+        return (self.telemetry if self.telemetry is not None
+                else process_recorder())
 
     def _mesh_scope(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
@@ -73,7 +82,9 @@ class Run:
                 next(stream)
             except StopIteration:
                 break
-        return Prefetcher(stream, place=make_placer(self.mesh, self.rules))
+        rec = self.recorder
+        return Prefetcher(stream, place=make_placer(self.mesh, self.rules,
+                                                    rec), recorder=rec)
 
     @property
     def data(self) -> Prefetcher:
@@ -97,10 +108,12 @@ class Run:
 
     def step(self, batch, step_idx: int = 0):
         """Run one (jit) train step on an explicit batch; advances the run's
-        params/opt_state and returns the metrics dict."""
+        params/opt_state and returns the metrics dict.  The call only
+        enqueues the step (``step.dispatch``); the metrics are futures."""
         with self._mesh_scope():
-            self.params, self.opt_state, metrics = self.jit_step(
-                self.params, self.opt_state, step_idx, batch)
+            self.params, self.opt_state, metrics = dispatch_step(
+                self.recorder, self.jit_step, self.params, self.opt_state,
+                step_idx, batch)
         self._warm = True
         return metrics
 
@@ -234,8 +247,9 @@ class Run:
         trajectory continues exactly where the interrupted run left off.
         Pass ``start_step=0`` to force a fresh run.  Per-step hooks attach
         to ``run.telemetry`` (``add_listener``) — every trainer phase is an
-        event; the cluster launcher's heartbeat listens for the "step"
-        span, which replaced the old bare ``on_step`` callback."""
+        event; the cluster launcher's heartbeat listens for the
+        "step.dispatch" span, which replaced the old bare ``on_step``
+        callback."""
         s = self.spec
         if start_step is None:
             start_step = 0
@@ -262,7 +276,7 @@ class Run:
         tcfg = TrainerConfig(total_steps=s.steps, log_every=s.log_every,
                              ckpt_every=s.ckpt_every, ckpt_dir=s.ckpt_dir,
                              ckpt_meta=self._ckpt_meta(),
-                             recorder=self.telemetry)
+                             recorder=self.recorder)
         trainer = Trainer(self.jit_step, tcfg, jit=False, warm=self._warm)
         with self._mesh_scope():
             self.params, self.opt_state, history = trainer.fit(
@@ -281,6 +295,8 @@ class Run:
             self._data = None
         rec = self.telemetry
         if rec is not None and getattr(rec, "enabled", False):
+            # detaches the run's sinks; the process recorder's aggregates
+            # stay readable after the run
             rec.close()
             if rec.trace_dir:
                 # single-process runs merge their own Chrome trace; cluster

@@ -17,8 +17,8 @@ Liveness is tracked two ways, consumed by ``cluster.elastic``:
   * the OS process itself (``Popen.poll`` — a crash or a SIGKILL chaos
     injection is detected within one poll interval);
   * a per-worker heartbeat file, written by a telemetry listener riding the
-    training loop's "step" span (``make_heartbeat_listener`` attached to
-    ``run.telemetry``), which catches the nastier failure mode of a worker
+    training loop's "step.dispatch" span (``make_heartbeat_listener``
+    attached to ``run.telemetry``), which catches the nastier failure mode of a worker
     that is alive but wedged in a collective whose peer died.
 
 The heartbeat payload is JSON ``{"step": n, "mono": t}`` carrying the
@@ -94,11 +94,12 @@ def parse_heartbeat(path: str) -> Optional[Heartbeat]:
 
 
 def make_heartbeat_listener(path: str) -> Callable[[dict], None]:
-    """A telemetry listener that beats ``path`` on every completed "step"
-    span — attach to ``run.telemetry.add_listener``.  The beat carries the
-    span's end timestamp (``t1``, the worker's monotonic clock) and step."""
+    """A telemetry listener that beats ``path`` on every completed
+    "step.dispatch" span — attach to ``run.telemetry.add_listener``.  The
+    beat carries the span's end timestamp (``t1``, the worker's monotonic
+    clock) and step."""
     def listener(ev: dict) -> None:
-        if ev.get("kind") == "step" and ev.get("ph") == "span":
+        if ev.get("kind") == "step.dispatch" and ev.get("ph") == "span":
             try:
                 write_heartbeat(path, int(ev.get("step", 0)),
                                 float(ev["t1"]))

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from repro.comm.bucketer import Bucket, BucketPlan, CommConfig, plan_buckets
 from repro.comm.schedule import Schedule, make_schedule, reduce_mean
 from repro.core.collectives import AxisNames
+from repro.telemetry import scopes
 
 
 # ---------------------------------------------------------------------------
@@ -98,12 +99,13 @@ def _bucket_tap(bucket: Bucket, sched: Schedule, wire_dtype, G: int):
         return leaves, None
 
     def bwd(_, ct):
-        parts = [c.reshape(-1) for c in ct]
-        pad = bucket.padded_size - bucket.size
-        if pad:
-            parts.append(jnp.zeros((pad,), parts[0].dtype))
-        buf = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-        strip = reduce_mean(sched, buf, wire_dtype, G)
+        with jax.named_scope(scopes.REDUCE):
+            parts = [c.reshape(-1) for c in ct]
+            pad = bucket.padded_size - bucket.size
+            if pad:
+                parts.append(jnp.zeros((pad,), parts[0].dtype))
+            buf = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+            strip = reduce_mean(sched, buf, wire_dtype, G)
         # leaf cotangents pass through untouched — upstream backprop is
         # unaffected; the strip rides the sink's gradient channel
         return tuple(ct), strip
@@ -142,7 +144,8 @@ def make_overlap_grad(loss_fn: Callable, axes: AxisNames, comm: CommConfig,
                     tuple(out[s.index] for s in b.slots), sink)
                 for s, leaf in zip(b.slots, tapped):
                     out[s.index] = leaf
-            return loss_fn(jax.tree.unflatten(treedef, out), batch)
+            with jax.named_scope(scopes.FWD):
+                return loss_fn(jax.tree.unflatten(treedef, out), batch)
 
         sinks = tuple(jnp.zeros((b.padded_size // G,), jnp.float32)
                       for b in plan.buckets)

@@ -5,6 +5,18 @@ compute library.  Here a background thread fills a bounded queue with
 host-side numpy batches (double buffering), and batches are placed onto the
 mesh with the batch-dim sharding before the step consumes them.
 
+Its spans (``repro.telemetry``, the process recorder unless one is given):
+``data.wait`` is the whole hand-off of a batch (``Prefetcher.__next__``),
+``data.place`` its child, the placer call, and ``data.h2d`` runs from the
+start of placement until the host-to-device copy of every array of the
+batch has landed.  On a mesh of more than one device the copy lands on the
+default device and is then spread over the mesh: ``data.reshard`` runs from
+that spread's dispatch until the placed batch is ready, so it also waits
+for the steps queued on the device ahead of it.  Placement only queues the
+copies (the runtime lays the batch out for the device on its own threads),
+so each of the two spans is timed by a long-lived observer thread of its
+own that blocks on readiness; the training thread never waits for them.
+
 Streams are synthetic but deterministic (seeded): LM token streams with a
 Zipf-ish unigram plus a learnable bigram structure (so losses actually fall),
 image/label streams for the CNNs, frame/senone streams for CD-DNN, and the
@@ -23,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
+from repro.telemetry.events import process_recorder
 
 
 # ---------------------------------------------------------------------------
@@ -128,18 +141,60 @@ _SENTINEL = object()    # queued when the source is exhausted: a finite
 #                         leave it blocked on an empty queue forever
 
 
+class _SpanObserver:
+    """A daemon thread that takes ``(recorder, t0, arrays)`` and records
+    its span from ``t0`` to the moment every array is ready.  ``submit``
+    never blocks.  The queue holds only batches whose copies are still
+    pending, as many as the pipeline has in flight."""
+
+    def __init__(self, kind: str):
+        self.kind = kind
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._t = threading.Thread(target=self._run, name=kind, daemon=True)
+        self._t.start()
+
+    def submit(self, rec, t0: float, arrays) -> None:
+        self._q.put((rec, t0, arrays))
+
+    def _run(self) -> None:
+        while True:
+            rec, t0, arrays = self._q.get()
+            try:
+                jax.block_until_ready(arrays)
+            except Exception:      # noqa: BLE001 — deleted or failed copy
+                continue
+            finally:
+                del arrays
+            rec.record_span(self.kind, t0, rec.clock())
+
+
+_OBSERVERS: Dict[str, _SpanObserver] = {}
+_OBSERVERS_LOCK = threading.Lock()
+
+
+def _observer(kind: str) -> _SpanObserver:
+    """The process's one observer of span ``kind``."""
+    with _OBSERVERS_LOCK:
+        if kind not in _OBSERVERS:
+            _OBSERVERS[kind] = _SpanObserver(kind)
+        return _OBSERVERS[kind]
+
+
 class Prefetcher:
     """Background-thread prefetch with a bounded queue (double buffering).
 
     Finite sources terminate cleanly: exhaustion enqueues a sentinel that
     ``__next__`` turns into ``StopIteration``.  ``close()`` stops the
     worker, drains the queue and JOINS the thread (bounded), so no worker
-    is left blocked on a full queue after the consumer goes away."""
+    is left blocked on a full queue after the consumer goes away.  Each
+    ``__next__`` is a ``data.wait`` span on ``recorder`` (the process
+    recorder by default)."""
 
     def __init__(self, source: Iterator, depth: int = 2,
-                 place: Optional[Callable] = None):
+                 place: Optional[Callable] = None, recorder=None):
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
-        self._place = place or (lambda b: jax.tree.map(jnp.asarray, b))
+        self._rec = recorder if recorder is not None else process_recorder()
+        self._place = place or make_placer(None, None, self._rec)
         self._stop = threading.Event()
         self._error: Optional[BaseException] = None
 
@@ -173,16 +228,17 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        item = self._q.get()
-        if item is _SENTINEL:
-            try:                      # keep raising on subsequent calls
-                self._q.put_nowait(_SENTINEL)
-            except queue.Full:
-                pass
-            if self._error is not None:
-                raise self._error
-            raise StopIteration
-        return self._place(item)
+        with self._rec.span("data.wait"):
+            item = self._q.get()
+            if item is _SENTINEL:
+                try:                      # keep raising on subsequent calls
+                    self._q.put_nowait(_SENTINEL)
+                except queue.Full:
+                    pass
+                if self._error is not None:
+                    raise self._error
+                raise StopIteration
+            return self._place(item)
 
     def close(self):
         self._stop.set()
@@ -194,15 +250,29 @@ class Prefetcher:
         self._t.join(timeout=5.0)
 
 
-def make_placer(mesh: Optional[Mesh], rules) -> Callable:
-    if mesh is None:
-        return lambda b: jax.tree.map(jnp.asarray, b)
+def make_placer(mesh: Optional[Mesh], rules, recorder=None) -> Callable:
+    """``place(batch)``: the batch on the devices, each field sharded over
+    the mesh's batch axes (or on the default device when ``mesh`` is None).
+    Each call is a ``data.place`` span on ``recorder`` (the process
+    recorder by default); the observer threads close its ``data.h2d`` span
+    when the copies have landed and, on a mesh of more than one device,
+    its ``data.reshard`` span when the batch is spread over the mesh."""
+    rec = recorder if recorder is not None else process_recorder()
+    spread = mesh is not None and mesh.size > 1
+
+    def shard(k, x):
+        axes = BATCH_SPECS.get(k, ("batch",) + (None,) * (x.ndim - 1))
+        return jax.device_put(x, rules.sharding(axes, x.shape, mesh))
 
     def place(batch):
-        out = {}
-        for k, v in batch.items():
-            axes = BATCH_SPECS.get(k, ("batch",) + (None,) * (v.ndim - 1))
-            sh = rules.sharding(axes, v.shape, mesh)
-            out[k] = jax.device_put(jnp.asarray(v), sh)
+        with rec.span("data.place") as span:
+            landed = jax.tree.map(jnp.asarray, batch)
+            t_spread = rec.clock() if spread and rec.enabled else None
+            out = landed if mesh is None else {
+                k: shard(k, x) for k, x in landed.items()}
+        if rec.enabled:
+            _observer("data.h2d").submit(rec, span.t0, landed)
+            if spread:
+                _observer("data.reshard").submit(rec, t_spread, out)
         return out
     return place

@@ -69,7 +69,7 @@ def worker_main(args) -> int:
               f"  parallel={run.spec.parallel}")
     hb = os.environ.get(ENV_HEARTBEAT_FILE)
     if hb:
-        # the heartbeat rides the telemetry "step" span (the general event
+        # the heartbeat rides the "step.dispatch" span (the general event
         # hook that replaced the bare on_step callback); compile_run always
         # builds a live recorder, so this works with or without --trace-dir
         run.telemetry.add_listener(make_heartbeat_listener(hb))

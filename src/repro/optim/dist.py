@@ -76,6 +76,7 @@ from repro.comm.bucketer import (
     unpack_buckets,
 )
 from repro.comm.schedule import Schedule, group_axes, make_schedule, reduce_mean
+from repro.telemetry import scopes
 
 DEFAULT_COMM = CommConfig()
 
@@ -169,9 +170,10 @@ class UpdatePlan:
         fusion buffer, reduce on the wire dtype, mean in fp32.  Returns
         this member's mean-gradient strip per bucket."""
         flat_grads = jax.tree.leaves(grads)
-        return [reduce_mean(sched, pack_bucket(flat_grads, b),
-                            self.comm.wire_dtype, self.G)
-                for b in plan.buckets]
+        with jax.named_scope(scopes.REDUCE):
+            return [reduce_mean(sched, pack_bucket(flat_grads, b),
+                                self.comm.wire_dtype, self.G)
+                    for b in plan.buckets]
 
     def apply(self, sched: Schedule, plan: BucketPlan, params, g_strips,
               opt_state, lr):
@@ -181,27 +183,29 @@ class UpdatePlan:
         shard_map-local layout (strips as (1, n/G) rows) and the new state
         leaves the same way."""
         flat_params = jax.tree.leaves(params)
-        i = sched.owner_index()
-        p_strips = []
-        for b in plan.buckets:
-            pbuf = pack_bucket(flat_params, b)
-            n = b.padded_size // self.G
-            p_strips.append(lax.dynamic_slice(pbuf, (i * n,), (n,)))
-        s_local = jax.tree.map(
-            lambda s: s[0] if s.ndim >= 2 else s, opt_state)
-        new_p_strips, new_state = self.optimizer.update(g_strips, s_local,
-                                                        p_strips, lr)
-        new_state = jax.tree.map(
-            lambda s: s[None] if s.ndim >= 1 else s, new_state)
+        with jax.named_scope(scopes.APPLY):
+            i = sched.owner_index()
+            p_strips = []
+            for b in plan.buckets:
+                pbuf = pack_bucket(flat_params, b)
+                n = b.padded_size // self.G
+                p_strips.append(lax.dynamic_slice(pbuf, (i * n,), (n,)))
+            s_local = jax.tree.map(
+                lambda s: s[0] if s.ndim >= 2 else s, opt_state)
+            new_p_strips, new_state = self.optimizer.update(
+                g_strips, s_local, p_strips, lr)
+            new_state = jax.tree.map(
+                lambda s: s[None] if s.ndim >= 1 else s, new_state)
         return jax.tree.leaves(new_p_strips), new_state
 
     def broadcast(self, sched: Schedule, plan: BucketPlan, params,
                   new_p_strips):
         """Phase 4: one part-broadcast per bucket (always fp32 — weights
         are never quantized on the wire), then un-fuse back into tensors."""
-        bufs = [sched.broadcast(ps) for ps in new_p_strips]
-        treedef = jax.tree.structure(params)
-        return jax.tree.unflatten(treedef, unpack_buckets(bufs, plan))
+        with jax.named_scope(scopes.BROADCAST):
+            bufs = [sched.broadcast(ps) for ps in new_p_strips]
+            flat = unpack_buckets(bufs, plan)
+        return jax.tree.unflatten(jax.tree.structure(params), flat)
 
     # -- shard_map plumbing shared by the monolithic wrappers ----------
     def wrap_update(self, _update):
@@ -330,12 +334,13 @@ def make_stale_sync_update(optimizer, mesh: Mesh, data_axes=("data",),
         plan = up.buckets(params)
         sched = up.schedule(step)
         fresh = up.reduce(sched, plan, grads)
-        carried = [s[0] for s in opt_state["stale"]]
-        synced = opt_state["synced"]
-        # consume LAST step's reduce; an empty buffer (first step, or a
-        # resume that re-initialized it) falls back to this step's own
-        applied = [jnp.where(synced > 0, c, f)
-                   for c, f in zip(carried, fresh)]
+        with jax.named_scope(scopes.APPLY):
+            carried = [s[0] for s in opt_state["stale"]]
+            synced = opt_state["synced"]
+            # consume LAST step's reduce; an empty buffer (first step, or a
+            # resume that re-initialized it) falls back to this step's own
+            applied = [jnp.where(synced > 0, c, f)
+                       for c, f in zip(carried, fresh)]
         new_p_strips, new_inner = up.apply(sched, plan, params, applied,
                                            opt_state["zero1"], lr)
         new_params = up.broadcast(sched, plan, params, new_p_strips)
@@ -402,18 +407,21 @@ def make_topk_ef_update(optimizer, mesh: Mesh, data_axes=("data",),
         flat_grads = jax.tree.leaves(grads)
         g_strips, new_res = [], []
         for b, res in zip(plan.buckets, opt_state["residual"]):
-            buf = pack_bucket(flat_grads, b).astype(jnp.float32) + res[0]
-            # floor G: every wire chunk must get at least one entry, and
-            # the per-chunk k the backend re-selects with (ratio * n/G,
-            # floored at 1) then carries at least the bucket's k/G — mass
-            # that concentrates in one chunk beyond its per-chunk k is
-            # dropped on the wire, the canonical gTop-k approximation,
-            # and lands back in the residual via error feedback
-            k = topk_chunk_k(b.padded_size, up.comm.topk_ratio, floor=up.G)
-            kept = topk_mask_ref(buf, k)
-            new_res.append((buf - kept)[None])
-            g_strips.append(reduce_mean(sched, kept, up.comm.wire_dtype,
-                                        up.G))
+            with jax.named_scope(scopes.REDUCE):
+                buf = pack_bucket(flat_grads, b).astype(jnp.float32) + res[0]
+                # floor G: every wire chunk must get at least one entry,
+                # and the per-chunk k the backend re-selects with (ratio *
+                # n/G, floored at 1) then carries at least the bucket's k/G
+                # — mass that concentrates in one chunk beyond its
+                # per-chunk k is dropped on the wire, the canonical gTop-k
+                # approximation, and lands back in the residual via error
+                # feedback
+                k = topk_chunk_k(b.padded_size, up.comm.topk_ratio,
+                                 floor=up.G)
+                kept = topk_mask_ref(buf, k)
+                new_res.append((buf - kept)[None])
+                g_strips.append(reduce_mean(sched, kept,
+                                            up.comm.wire_dtype, up.G))
         new_p_strips, new_inner = up.apply(sched, plan, params, g_strips,
                                            opt_state["zero1"], lr)
         new_params = up.broadcast(sched, plan, params, new_p_strips)

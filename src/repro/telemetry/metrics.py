@@ -2,15 +2,17 @@
 
 The paper's analysis (§3.1–3.2) is about DISTRIBUTIONS of per-phase times,
 not single samples — exposed communication is a tail phenomenon.  So the
-aggregation primitive here is a reservoir-free histogram that keeps raw
-observations (runs are short enough that exact percentiles beat bucketed
-approximations) and computes numpy-convention percentiles, which is what
-``Server.latency_stats`` and ``benchmarks/serve_load.py`` report instead of
-re-sorting request lists by hand.
+aggregation primitive here is a histogram that keeps raw values (exact
+percentiles beat bucketed approximations) and computes numpy-convention
+percentiles, which is what ``Server.latency_stats`` and
+``benchmarks/serve_load.py`` report instead of re-sorting request lists by
+hand.  A recorder's histograms keep only the most recent ``WINDOW``
+observations, so a long-lived process holds a bounded amount per span.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -37,14 +39,18 @@ class Gauge:
         self.value = float(v)
 
 
+WINDOW = 4096      # observations a recorder's histogram keeps
+
+
 class Histogram:
-    """Exact-sample histogram: keeps every observation and answers
-    numpy-convention percentiles (linear interpolation — the same numbers
-    ``np.percentile`` gives, asserted in tests/test_telemetry.py)."""
+    """Exact-sample histogram: numpy-convention percentiles (linear
+    interpolation — the same numbers ``np.percentile`` gives, asserted in
+    tests/test_telemetry.py) over every observation, or over the last
+    ``window`` of them when one is given (older ones fall out)."""
     __slots__ = ("_values",)
 
-    def __init__(self) -> None:
-        self._values: List[float] = []
+    def __init__(self, window: Optional[int] = None) -> None:
+        self._values: deque = deque(maxlen=window)
 
     def observe(self, v: float) -> None:
         self._values.append(float(v))

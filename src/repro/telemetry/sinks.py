@@ -11,9 +11,13 @@ Per-process layout under a ``--trace-dir``:
 Single-process runs merge their own lone file at ``Run.close``; cluster
 runs leave the per-process files to the SUPERVISOR's merge
 (``launch.cluster``) — workers cannot merge, they'd race each other.
-Timestamps are per-process ``time.monotonic``; the merge rebases each
-process to its own first event so the timelines align at 0 (cross-process
-skew is not meaningful across monotonic clocks and is not implied).
+Timestamps in the JSONL are per-process ``time.monotonic`` (durations and
+the heartbeat survive NTP jumps); each file's first line, the ``meta``
+event, carries its recorder's anchor (``mono`` and the ``wall_ns`` read
+beside it), and the Chrome trace puts every event on the wall clock
+through it: ``ts`` is microseconds since the epoch, the clock of a JAX
+profiler trace.  Merged processes therefore share one timeline, as far as
+the hosts' wall clocks agree.
 """
 from __future__ import annotations
 
@@ -23,7 +27,7 @@ import os
 import re
 from typing import Dict, List, Optional
 
-_SPAN_META = ("kind", "ph", "t0", "t1", "dur", "depth")
+_SPAN_META = ("kind", "ph", "t0", "t1", "dur", "depth", "tid")
 
 
 def trace_path(trace_dir: str, process_index: int) -> str:
@@ -61,27 +65,32 @@ def read_jsonl(path: str) -> List[dict]:
 
 
 def to_chrome_events(events: List[dict], pid: int = 0,
-                     name: Optional[str] = None,
-                     ts_offset: Optional[float] = None) -> List[dict]:
+                     name: Optional[str] = None) -> List[dict]:
     """Recorder events -> Chrome trace ``traceEvents`` (``ph: "X"``
     complete spans, ``ph: "i"`` instants; ``ts``/``dur`` in microseconds).
-    ``ts_offset`` rebases timestamps (defaults to the earliest ``t0``)."""
+    ``ts`` is on the wall clock, through the anchor of the ``meta`` event
+    among ``events``; without one the timestamps count from the earliest
+    event."""
     spans = [e for e in events if "t0" in e]
-    if ts_offset is None:
-        ts_offset = min((e["t0"] for e in spans), default=0.0)
+    meta = next((e for e in events
+                 if e.get("kind") == "meta" and "wall_ns" in e), None)
+    if meta is not None:
+        mono0, base_us = meta["mono"], meta["wall_ns"] / 1e3
+    else:
+        mono0, base_us = min((e["t0"] for e in spans), default=0.0), 0.0
     out = []
     if name:
         out.append({"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
                     "args": {"name": name}})
     for e in spans:
         args = {k: v for k, v in e.items() if k not in _SPAN_META}
-        ts = (e["t0"] - ts_offset) * 1e6
+        ts = base_us + (e["t0"] - mono0) * 1e6
         if e.get("ph") == "span":
-            # nested spans share tid 0 — Chrome stacks "X" events that
-            # nest in time into the flame rows itself
+            # one row per recording thread — Chrome stacks "X" events
+            # that nest in time into the flame rows itself
             out.append({"name": e["kind"], "cat": "span", "ph": "X",
                         "ts": ts, "dur": e["dur"] * 1e6, "pid": pid,
-                        "tid": 0, "args": args})
+                        "tid": e.get("tid", 0), "args": args})
         else:
             out.append({"name": e["kind"], "cat": "instant", "ph": "i",
                         "ts": ts, "pid": pid, "tid": 0, "s": "p",

@@ -17,6 +17,10 @@ Two equivalent realizations of the paper's §3.4 update:
 overlap schedule: the whole step runs inside one shard_map and each gradient
 bucket's part-reduce is issued INSIDE the backward pass (repro.comm.overlap)
 instead of after ``value_and_grad`` returns.
+
+Every realization opens the step-phase scopes of ``repro.telemetry.scopes``
+(``fwd``, ``clip``, ``update/reduce|apply|broadcast``), so each compiled op
+names its phase; the scopes are metadata and leave the arithmetic alone.
 """
 from __future__ import annotations
 
@@ -28,6 +32,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.sharding import ShardingRules
+from repro.telemetry import scopes
 
 
 def global_norm(tree) -> jax.Array:
@@ -52,19 +57,27 @@ def make_train_step(loss_fn: Callable, optimizer, lr_schedule,
     see the train step; step-free modes ignore it.  The matching
     ``opt_state`` must come from the same builder's ``init_fn``.
     """
+    def scoped_loss(params, batch):
+        with jax.named_scope(scopes.FWD):
+            return loss_fn(params, batch)
+
     def train_step(params, opt_state, step_idx, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        gnorm = global_norm(grads)
-        if grad_clip > 0:
-            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-9))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-        lr = lr_schedule(step_idx)
+        loss, grads = jax.value_and_grad(scoped_loss)(params, batch)
+        with jax.named_scope(scopes.CLIP):
+            gnorm = global_norm(grads)
+            if grad_clip > 0:
+                scale = jnp.minimum(1.0,
+                                    grad_clip / jnp.maximum(gnorm, 1e-9))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+        with jax.named_scope(scopes.APPLY):
+            lr = lr_schedule(step_idx)
         if dist_update is not None:
             new_params, new_state = dist_update(params, grads, opt_state, lr,
                                                 step_idx)
         else:
-            new_params, new_state = optimizer.update(grads, opt_state,
-                                                     params, lr)
+            with jax.named_scope(scopes.APPLY):
+                new_params, new_state = optimizer.update(grads, opt_state,
+                                                         params, lr)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         return new_params, new_state, metrics
 
@@ -100,16 +113,21 @@ def make_overlapped_train_step(loss_fn: Callable, lr_schedule,
 
     def local_step(params, opt_state, step_idx, batch):
         loss, g_strips = overlap_grad(params, batch)
-        loss = lax.psum(loss, axis_arg) / G
-        # global grad norm from the reduced strips: every element of the
-        # mean gradient lives in exactly one member's strip (bucket padding
-        # is zeros), so the psum of local square-sums is the full norm²
-        sq = sum(jnp.sum(jnp.square(s)) for s in g_strips)
-        gnorm = jnp.sqrt(lax.psum(sq, axis_arg))
-        if grad_clip > 0:
-            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-9))
-            g_strips = [g * scale for g in g_strips]
-        lr = lr_schedule(step_idx)
+        with jax.named_scope(scopes.FWD):
+            loss = lax.psum(loss, axis_arg) / G
+        with jax.named_scope(scopes.CLIP):
+            # global grad norm from the reduced strips: every element of
+            # the mean gradient lives in exactly one member's strip (bucket
+            # padding is zeros), so the psum of local square-sums is the
+            # full norm²
+            sq = sum(jnp.sum(jnp.square(s)) for s in g_strips)
+            gnorm = jnp.sqrt(lax.psum(sq, axis_arg))
+            if grad_clip > 0:
+                scale = jnp.minimum(1.0,
+                                    grad_clip / jnp.maximum(gnorm, 1e-9))
+                g_strips = [g * scale for g in g_strips]
+        with jax.named_scope(scopes.APPLY):
+            lr = lr_schedule(step_idx)
         new_params, new_state = local_update(params, g_strips, opt_state, lr)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         return new_params, new_state, metrics

@@ -13,7 +13,7 @@ from typing import Any, Callable, Iterable, Optional
 import jax
 
 from repro.checkpoint import ckpt as ckpt_lib
-from repro.telemetry.events import NULL_RECORDER
+from repro.telemetry.events import process_recorder
 
 
 def _batch_items(batch) -> tuple:
@@ -34,6 +34,17 @@ def _batch_items(batch) -> tuple:
     return 0, "samples"
 
 
+def dispatch_step(rec, step_fn, params, opt_state, step: int, batch):
+    """Enqueue one train step: the ``step.dispatch`` span around the
+    (jitted) call, and the ``step.steps`` and ``step.samples`` counters.
+    ``Run.step`` and ``Trainer.fit`` both dispatch through here."""
+    with rec.span("step.dispatch", step=step + 1):
+        out = step_fn(params, opt_state, step, batch)
+    rec.count("step.steps")
+    rec.count("step.samples", int(jax.tree.leaves(batch)[0].shape[0]))
+    return out
+
+
 @dataclass
 class TrainerConfig:
     total_steps: int = 100
@@ -45,14 +56,15 @@ class TrainerConfig:
     #                                    world-size replan — see
     #                                    checkpoint.replan)
     recorder: Optional[Any] = None     # telemetry Recorder; every phase of
-    #                                    the loop becomes a span (step,
-    #                                    data_wait, compile, ckpt_write) and
-    #                                    listeners see each completed event
-    #                                    — the general hook that replaced
-    #                                    the bare on_step heartbeat callback
-    #                                    (the cluster heartbeat now rides
-    #                                    the "step" span's end event).
-    #                                    None = NULL_RECORDER (no-op).
+    #                                    the loop becomes a span
+    #                                    (step.dispatch, compile,
+    #                                    ckpt_write) and listeners see each
+    #                                    completed event — the general hook
+    #                                    that replaced the bare on_step
+    #                                    heartbeat callback (the cluster
+    #                                    heartbeat rides the
+    #                                    "step.dispatch" span's end event).
+    #                                    None = the process recorder.
 
 
 @dataclass
@@ -71,15 +83,13 @@ class Trainer:
         step_fn = jax.jit(self.train_step, donate_argnums=(0, 1)) \
             if self.jit else self.train_step
         rec = self.cfg.recorder if self.cfg.recorder is not None \
-            else NULL_RECORDER
-        sync = getattr(rec, "sync", False)
+            else process_recorder()
         t0 = time.perf_counter()
         t_compile = 0.0
         items_seen, unit = 0, "tok"
         for step in range(start_step, self.cfg.total_steps):
             try:
-                with rec.span("data_wait", step=step + 1):
-                    batch = next(data_iter)
+                batch = next(data_iter)
             except StopIteration:
                 # finite source ran dry (Prefetcher signals exhaustion as
                 # StopIteration): end training with the progress made, do
@@ -88,27 +98,19 @@ class Trainer:
                        f"(of {self.cfg.total_steps}); stopping")
                 break
             first = step == start_step and not self.warm
-            with rec.span("step", step=step + 1):
-                params, opt_state, metrics = step_fn(params, opt_state,
-                                                     step, batch)
-                if first:
-                    # the first step is dominated by jit compile: block,
-                    # report it separately, and restart the throughput clock
-                    # so items/s measures steady-state steps only
-                    with rec.span("compile", step=step + 1):
-                        jax.block_until_ready(metrics["loss"])
-                elif sync:
-                    # traced runs trade async dispatch for honest span
-                    # durations; untraced runs never block here
-                    jax.block_until_ready(metrics["loss"])
+            params, opt_state, metrics = dispatch_step(
+                rec, step_fn, params, opt_state, step, batch)
             if first:
+                # the first step is dominated by jit compile: block, report
+                # it separately, and restart the throughput clock so the
+                # logged rate measures steady-state steps only
+                with rec.span("compile", step=step + 1):
+                    jax.block_until_ready(metrics["loss"])
                 t_compile = time.perf_counter() - t0
                 t0 = time.perf_counter()
             else:
                 n, unit = _batch_items(batch)
                 items_seen += n
-                rec.count(f"items_{unit}", n)
-            rec.count("steps")
             # the FINAL step always logs, so history[-1] is the true end
             # state (callers label checkpoints / report final loss from it)
             if ((step + 1) % self.cfg.log_every == 0 or step == start_step

@@ -109,6 +109,214 @@ def test_null_recorder_overhead_is_cheap():
 
 
 # ---------------------------------------------------------------------------
+# the process recorder: wall clock, bounded memory, data and step spans
+# ---------------------------------------------------------------------------
+
+def test_spans_export_on_the_wall_clock():
+    """The recorder's anchor puts monotonic spans on time.time_ns(), the
+    clock of a profiler trace: each exported span lies inside wall-clock
+    readings taken around it (to the anchor's read skew, 0.1 ms)."""
+    from repro.telemetry import Recorder
+    r = Recorder()
+    slack = 100_000
+    for _ in range(3):
+        before = time.time_ns()
+        with r.span("data.wait"):
+            time.sleep(0.002)
+        after = time.time_ns()
+        name, t0, t1 = r.spans_ns()[-1]
+        assert name == "data.wait"
+        assert before - slack <= t0 < t1 <= after + slack
+        assert t1 - t0 >= 2_000_000
+
+
+def test_histograms_and_event_ring_stay_bounded():
+    from repro.telemetry import Histogram, JsonlSink, Recorder
+    from repro.telemetry.metrics import WINDOW
+    r = Recorder()
+    for _ in range(WINDOW + 100):
+        with r.span("step.dispatch"):
+            pass
+    assert len(r.events) == WINDOW
+    assert r.hist("span/step.dispatch_s").count == WINDOW
+    h, every = Histogram(WINDOW), Histogram()
+    for v in range(WINDOW + 904):
+        h.observe(float(v))
+        every.observe(float(v))
+    assert h.count == WINDOW and h.percentile(0) == 904.0
+    # only a recorder's histograms are windowed
+    assert every.count == WINDOW + 904 and every.percentile(0) == 0.0
+    # a sink writes the events, so the ring keeps none of them
+    with tempfile.TemporaryDirectory() as td:
+        sink = JsonlSink(os.path.join(td, "t.jsonl"))
+        r.add_sink(sink)
+        r.events.clear()
+        with r.span("step.dispatch"):
+            pass
+        r.remove(sink)
+        sink.close()
+        assert len(r.events) == 0
+        assert len(open(os.path.join(td, "t.jsonl")).readlines()) == 1
+
+
+def test_data_place_nests_in_data_wait():
+    import jax
+
+    from repro.data.pipeline import Prefetcher, make_placer
+    from repro.telemetry import Recorder
+    r = Recorder()
+    src = iter([{"x": np.full((32, 8), i, np.float32)} for i in range(3)])
+    pf = Prefetcher(src, place=make_placer(None, None, r), recorder=r)
+    got = [jax.block_until_ready(b) for b in pf]
+    pf.close()
+    assert [float(b["x"][0, 0]) for b in got] == [0.0, 1.0, 2.0]
+    deadline = time.monotonic() + 10.0
+    while (sum(e["kind"] == "data.h2d" for e in list(r.events)) < 3
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    by = {k: [e for e in r.events if e["kind"] == k]
+          for k in ("data.wait", "data.place", "data.h2d")}
+    assert [len(v) for v in by.values()] == [4, 3, 3]  # + the end of data
+    for wait, place, h2d in zip(by["data.wait"], by["data.place"],
+                                by["data.h2d"]):
+        assert wait["t0"] <= place["t0"] <= place["t1"] <= wait["t1"]
+        assert place["depth"] == wait["depth"] + 1
+        # data.h2d runs from the start of placement to readiness
+        assert h2d["t0"] == place["t0"] and h2d["t1"] >= place["t1"]
+
+
+def test_h2d_ends_after_the_placed_arrays_are_ready():
+    """The observer thread closes data.h2d only once every array of the
+    batch is ready; the submitting thread does not wait."""
+    import threading
+
+    from repro.data import pipeline
+    from repro.telemetry import Recorder
+
+    class Landing:                      # an array whose copy lands on cue
+        def __init__(self, gate):
+            self.gate = gate
+
+        def block_until_ready(self):
+            assert self.gate.wait(10.0)
+            return self
+
+    r = Recorder()
+    gate = threading.Event()
+    t0 = r.clock()
+    pipeline._observer("data.h2d").submit(r, t0, {"a": Landing(gate),
+                                                  "b": Landing(gate)})
+    time.sleep(0.05)
+    assert not any(e["kind"] == "data.h2d" for e in r.events)
+    landed = r.clock()
+    gate.set()
+    deadline = time.monotonic() + 10.0
+    while not r.events and time.monotonic() < deadline:
+        time.sleep(0.005)
+    h2d, = [e for e in r.events if e["kind"] == "data.h2d"]
+    assert h2d["t0"] == t0 and h2d["t1"] >= landed
+
+
+def test_batch_spread_over_a_mesh_is_its_own_span():
+    """On a mesh of four (CPU) devices data.h2d ends when the copy has
+    landed and data.reshard, from the spread's dispatch inside data.place,
+    when the sharded batch is ready; a one-device mesh has no spread."""
+    code = textwrap.dedent("""
+        import json, time
+        import numpy as np
+        import jax
+        from jax.sharding import Mesh
+        from repro.core.sharding import ShardingRules
+        from repro.data.pipeline import make_placer
+        from repro.telemetry import Recorder
+        out = {}
+        for n in (4, 1):
+            r = Recorder()
+            mesh = Mesh(np.array(jax.devices()[:n]).reshape(n, 1),
+                        ("data", "model"))
+            place = make_placer(mesh, ShardingRules(), r)
+            b = place({"images": np.ones((8, 4, 4, 3), np.float32),
+                       "labels": np.zeros((8,), np.int32)})
+            jax.block_until_ready(b)
+            out[n] = {"shards": len(b["images"].addressable_shards)}
+            kinds = ("data.place", "data.h2d", "data.reshard")
+            deadline = time.monotonic() + 10
+            while (sum(e["kind"] in kinds[1:] for e in r.events) < min(n, 2)
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            time.sleep(0.05)
+            for k in kinds:
+                out[n][k] = [(e["t0"], e["t1"]) for e in r.events
+                             if e["kind"] == k]
+        print(json.dumps(out))
+    """)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"))
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    four, one = out["4"], out["1"]
+    assert four["shards"] == 4 and one["shards"] == 1
+    (p0, p1), = four["data.place"]
+    (h0, h1), = four["data.h2d"]
+    (s0, s1), = four["data.reshard"]
+    assert h0 == p0 and p0 <= s0 <= p1 and s1 >= s0
+    assert len(one["data.h2d"]) == 1 and one["data.reshard"] == []
+
+
+def test_run_step_and_fit_emit_step_dispatch_and_counters():
+    from repro.api import RunSpec, compile_run
+    from repro.telemetry import process_recorder
+    rec = process_recorder()
+
+    def counters():
+        c = rec.metrics()["counters"]
+        return c.get("step.steps", 0), c.get("step.samples", 0)
+
+    run = compile_run(RunSpec(arch="cd-dnn", smoke=True, parallel="serial",
+                              steps=3, batch=4, log_every=1,
+                              schedule="constant"))
+    seen = []
+    run.telemetry.add_listener(seen.append)
+    steps0, samples0 = counters()
+    run.step(next(run.data), 0)
+    run.fit(start_step=0, log_fn=lambda *_: None)
+    run.close()
+    dispatch = [e["step"] for e in seen if e["kind"] == "step.dispatch"]
+    assert dispatch == [1, 1, 2, 3]
+    assert {"data.wait", "data.place"} <= {e["kind"] for e in seen}
+    assert counters() == (steps0 + 4, samples0 + 16)
+    assert rec.hist("span/step.dispatch_s").count >= 4
+
+
+def test_closed_run_detaches_its_sinks(tmp_path):
+    from repro.api import RunSpec, compile_run
+    from repro.telemetry import process_recorder, read_jsonl, trace_path
+    rec = process_recorder()
+    run = compile_run(RunSpec(arch="cd-dnn", smoke=True, parallel="serial",
+                              batch=4, telemetry=str(tmp_path)))
+    run.step(next(run.data), 0)
+    run.close()
+    assert not rec._sinks and not rec._listeners
+    path = trace_path(str(tmp_path), rec.process_index)
+    n = len(read_jsonl(path))
+    with rec.span("step.dispatch", step=9):     # after close: not written
+        pass
+    lines = read_jsonl(path)
+    assert len(lines) == n
+    meta = lines[0]
+    assert meta["kind"] == "meta"
+    assert (meta["mono"], meta["wall_ns"]) == tuple(rec.anchor)
+    kinds = {e["kind"] for e in lines}
+    assert {"step.dispatch", "data.wait", "metrics"} <= kinds
+    # the aggregates outlive the run
+    assert rec.hist("span/step.dispatch_s").count >= 1
+
+
+# ---------------------------------------------------------------------------
 # metrics: histogram percentiles against numpy
 # ---------------------------------------------------------------------------
 
@@ -142,15 +350,19 @@ def test_jsonl_sink_and_chrome_trace_schema(tmp_path):
         read_jsonl,
         trace_path,
     )
+    before = time.time_ns()
     r = Recorder(process="train", process_index=0)
     sink = JsonlSink(trace_path(str(tmp_path), 0))
-    r.add_listener(sink)
-    r.event("meta", process="train", process_index=0, clock="monotonic")
+    r.add_sink(sink)
+    mono, wall = r.anchor
+    r.event("meta", process="train", process_index=0, clock="monotonic",
+            mono=mono, wall_ns=wall)
     with r.span("step", step=1):
         with r.span("compile", step=1):
             pass
     r.close()
     sink.close()
+    after = time.time_ns()
 
     lines = read_jsonl(trace_path(str(tmp_path), 0))
     assert [e["kind"] for e in lines][:3] == ["meta", "compile", "step"]
@@ -169,8 +381,10 @@ def test_jsonl_sink_and_chrome_trace_schema(tmp_path):
     assert any(e.get("ph") == "M" and e["args"]["name"] == "train[0]"
                for e in evs)
     assert any(e.get("ph") == "i" for e in evs)   # instants present
-    # ts rebased to the process's first event, so spans start near zero
-    assert min(e["ts"] for e in span_evs) < 1e6
+    # ts is wall-clock µs through the meta anchor: the profiler's clock
+    for e in span_evs:
+        assert before / 1e3 - 1 <= e["ts"] <= e["ts"] + e["dur"] \
+            <= after / 1e3 + 1
 
 
 def test_merge_process_traces_empty_dir_returns_none(tmp_path):
@@ -285,8 +499,10 @@ def test_comm_auto_run_matches_fixed_comm_loss_and_emits_trace():
     final loss as the fixed-comm run to tight tolerance (the §3.4 update is
     bucket-size INVARIANT, but the autotuner also picks the wire format
     jointly and int8's per-hop quantization is lossy), emits a loadable
-    Chrome trace containing step/data_wait/collective spans, and logs the
-    autotuned plan including the chosen wire format."""
+    Chrome trace containing step.dispatch/data.*/collective spans, and
+    logs the autotuned plan including the chosen wire format.  The second
+    run's events stay out of the first run's trace: its close detached
+    the sink."""
     with tempfile.TemporaryDirectory() as td:
         out = run_py(f"""
             import json
@@ -305,11 +521,13 @@ def test_comm_auto_run_matches_fixed_comm_loss_and_emits_trace():
             assert plan["args"]["chosen_wire_format"] in (
                 "fp32", "bf16", "int8"), plan
             names = {{e.get("name") for e in evs}}
-            for want in ("step", "data_wait", "collective",
-                         "autotune_plan", "autotune"):
+            for want in ("step.dispatch", "data.wait", "data.place",
+                         "data.h2d", "collective", "autotune_plan",
+                         "autotune"):
                 assert want in names, (want, names)
             steps = sorted(e["args"]["step"] for e in evs
-                           if e.get("name") == "step" and e.get("ph") == "X")
+                           if e.get("name") == "step.dispatch"
+                           and e.get("ph") == "X")
             assert steps == [1, 2, 3, 4], steps
             print("LOSS_EQUAL")
         """, devices=8)
@@ -380,6 +598,28 @@ def test_server_latency_stats_match_external_numpy():
     server.reset_latency_stats()
     assert server.latency_stats()["n"] == 0
     assert server.latency_stats()["e2e_p50_s"] is None
+
+
+def test_server_latency_stats_cover_every_request():
+    """The latency aggregates span every finished request, not a recent
+    window: the slow early requests stay in p99 past 4096 requests."""
+    from repro.api import ServeSpec, compile_serve
+    from repro.api.serve import Request
+    from repro.telemetry.metrics import WINDOW
+    server = compile_serve(ServeSpec(arch="llama3-8b", smoke=True,
+                                     max_batch=2, page_size=8, num_pages=16,
+                                     max_prompt=8, max_new_tokens=4,
+                                     prefill_bucket=8))
+    done, prompt = [], np.ones(4, np.int32)
+    for i in range(WINDOW + 1000):
+        t = time.perf_counter() - (10.0 if i < 1000 else 0.0)
+        server._finish(0, Request(i, prompt, 1, t, first_token_t=t),
+                       done)
+    stats = server.latency_stats()
+    e2e = np.array([r.latency for r in done])
+    assert stats["n"] == WINDOW + 1000
+    assert stats["e2e_p99_s"] == pytest.approx(np.percentile(e2e, 99))
+    assert stats["e2e_p99_s"] > 9.0
 
 
 def test_server_emits_prefill_and_decode_spans():
@@ -476,23 +716,26 @@ def test_heartbeat_listener_rides_step_spans(tmp_path):
     r = Recorder()
     hb = str(tmp_path / "hb")
     r.add_listener(make_heartbeat_listener(hb))
-    with r.span("data_wait", step=1):
+    with r.span("data.wait", step=1):
         pass
     assert parse_heartbeat(hb) is None        # only step spans beat
-    with r.span("step", step=1):
+    with r.span("step.dispatch", step=1):
         pass
     beat = parse_heartbeat(hb)
     assert beat.step == 1 and beat.mono is not None
-    step_ev = next(e for e in r.events if e["kind"] == "step")
+    step_ev = next(e for e in r.events if e["kind"] == "step.dispatch")
+    # the beat stays on the monotonic clock (NTP jumps cannot fake it)
     assert beat.mono == pytest.approx(step_ev["t1"])
+    assert abs(beat.mono - time.monotonic()) < 60.0
 
 
 def test_cluster_run_merges_per_process_traces():
     """2 real worker processes with --trace-dir: the supervisor must merge
     both workers' JSONL traces into one Chrome trace whose step spans are
-    per-process monotonic-consistent."""
+    per-process ordered, on the wall clock both processes share."""
     with tempfile.TemporaryDirectory() as td:
         env = dict(os.environ, PYTHONPATH=SRC)
+        t_start = time.time_ns() / 1e3
         out = subprocess.run(
             [sys.executable, "-m", "repro.launch.cluster",
              "--processes", "2", "--arch", "vgg-a", "--smoke",
@@ -503,16 +746,22 @@ def test_cluster_run_merges_per_process_traces():
         evs = json.load(open(os.path.join(td, "trace.json")))["traceEvents"]
         by_pid = {}
         for e in evs:
-            if e.get("name") == "step" and e.get("ph") == "X":
+            if e.get("name") == "step.dispatch" and e.get("ph") == "X":
                 by_pid.setdefault(e["pid"], []).append(e)
         assert set(by_pid) == {0, 1}, sorted(by_pid)
         for pid, spans in by_pid.items():
             spans.sort(key=lambda e: e["args"]["step"])
             assert [e["args"]["step"] for e in spans] == [1, 2, 3]
-            # within a process the rebased timestamps are ordered and
+            # within a process the timestamps are ordered and
             # non-overlapping (step N ends before step N+1 begins)
             for a, b in zip(spans, spans[1:]):
-                assert a["ts"] + a["dur"] <= b["ts"] + 1e-9
+                assert a["ts"] + a["dur"] <= b["ts"] + 1e-3
+        # both processes share the wall clock: every step lies inside the
+        # test's own window of time.time_ns() readings
+        t_end = time.time_ns() / 1e3
+        for spans in by_pid.values():
+            for e in spans:
+                assert t_start <= e["ts"] <= e["ts"] + e["dur"] <= t_end
 
 
 # ---------------------------------------------------------------------------
