@@ -47,13 +47,14 @@ from repro.api.families import FamilyAdapter, adapter_for
 from repro.api.run import Run
 from repro.api.serve import Server
 from repro.api.spec import MODE_CAPS, RunSpec, ServeSpec
-from repro.comm.bucketer import CommConfig
+from repro.comm.bucketer import CommConfig, plan_buckets
 from repro.configs import get_config, smoke_variant
 from repro.core.params import Spec
 from repro.core.sharding import ShardingCtx, ShardingRules
 from repro.launch.mesh import make_cluster_mesh, make_host_mesh
 from repro.optim import AdamW, MomentumSGD, constant, linear_scale_warmup, warmup_cosine
 from repro.optim.dist import (
+    ROW_BLOCK_SHARE,
     make_distributed_update,
     make_overlapped_update,
     make_stale_sync_update,
@@ -204,6 +205,10 @@ def assemble_step(spec: RunSpec, cfg, family: FamilyAdapter,
                                           mesh, rules)
             return jax.tree.map(jax.device_put, opt_state, st_sh)
 
+    # the update plan's share of elements in row-blocked buckets; 0 where
+    # the mode builds no plan
+    telemetry.gauge(ROW_BLOCK_SHARE, 0.0 if comm is None else plan_buckets(
+        params, data_ways, comm.bucket_bytes).row_block_share)
     if train_step is None:
         train_step = make_train_step(loss_fn, optimizer, lr_schedule,
                                      grad_clip=spec.grad_clip,

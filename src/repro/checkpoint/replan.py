@@ -25,6 +25,7 @@ the chaos test asserts.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -58,12 +59,15 @@ def replan_strip_leaf(arr: np.ndarray, payload: int, old_world: Dict,
     """One (G_old, padded_old/G_old) strip leaf -> (G_new, padded_new/G_new).
 
     ``payload`` is the bucket's real element count (G-independent); the
-    regions beyond it are the always-zero pad."""
+    regions beyond it are the always-zero pad.  A row-blocked leaf
+    (G_old, rows/G_old, cols) holds its strips' elements in the 1-D
+    order, so it is read as (G_old, -1)."""
     g_old, g_new = old_world["G"], new_world["G"]
-    if arr.ndim != 2 or arr.shape[0] != g_old:
+    if arr.ndim < 2 or arr.shape[0] != g_old:
         raise ValueError(
-            f"strip leaf has shape {arr.shape}, expected ({g_old}, n) "
+            f"strip leaf has shape {arr.shape}, expected ({g_old}, ...) "
             f"for the saved world size {g_old}")
+    arr = arr.reshape(g_old, -1)
     if arr.size != padded_size(payload, g_old):
         raise ValueError(
             f"strip leaf holds {arr.size} elements, bucket payload "
@@ -94,6 +98,8 @@ def replan_strip_state(template_state, old_leaves: List[np.ndarray],
     tensors cycling through the buckets in plan order (optimizer state is
     field-major: momentum[b0], momentum[b1], ..., m[b0], m[b1], ...), and
     everything else (e.g. the AdamW step count) passes through unchanged.
+    A strip leaf takes the template's shape, so a bucket row-blocked in
+    one world and flat in the other converts too.
     """
     if old_world.get("bucket_bytes") != new_world.get("bucket_bytes"):
         raise ValueError(
@@ -115,10 +121,11 @@ def replan_strip_state(template_state, old_leaves: List[np.ndarray],
             new = replan_strip_leaf(old, payloads[strip_i % len(payloads)],
                                     old_world, new_world)
             strip_i += 1
-            if tuple(new.shape) != tuple(tpl.shape):
+            if new.size != math.prod(tpl.shape):
                 raise ValueError(
                     f"replanned strip has shape {new.shape}, template "
                     f"expects {tuple(tpl.shape)}")
+            new = new.reshape(tpl.shape)
             out.append(new.astype(np.asarray(tpl).dtype
                                   if not hasattr(tpl, "dtype")
                                   else tpl.dtype))

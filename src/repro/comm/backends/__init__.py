@@ -35,10 +35,13 @@ per hop; topk moves (values, int32 indices) with per-hop re-selection.
 ``kernels.ref`` oracle math — the fallback reference), ``PallasRingBackend``
 fuses the combine into ``kernels/ring.py`` hop kernels.
 
-**Shapes.**  The schedules only ever pass 1-D fusion buffers whose size is
-a multiple of the group (``bucketer`` pads every bucket); a backend may
-reject anything else with ``NotImplementedError`` (``PallasRingBackend``
-does; ``LaxBackend`` is shape-general).
+**Shapes.**  The schedules pass 1-D fusion buffers whose size is a
+multiple of the group (``bucketer`` pads every bucket), and row-blocked
+``(rows, cols)`` buffers split along dim 0 only to a backend whose
+``takes_rows`` attribute is true (``LaxBackend`` on a dense wire); the
+others get such a buffer flattened.  A backend may reject anything else
+with ``NotImplementedError`` (``PallasRingBackend`` and ``GossipBackend``
+do).
 
 Selection is by name end-to-end: ``CommConfig(backend=...)`` →
 ``make_schedule`` → here.  ``HierarchicalSchedule`` takes one backend per

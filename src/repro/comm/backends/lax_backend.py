@@ -36,6 +36,13 @@ class LaxBackend:
     wire_format: str = "fp32"
     topk_ratio: float = 0.05
 
+    @property
+    def takes_rows(self) -> bool:
+        """The dense wires reduce and gather along dim 0 of a buffer of
+        any rank, so a row-blocked buffer needs no flattening; the
+        compressed ring runs on the 1-D form."""
+        return self.wire_format in ("fp32", "bf16")
+
     def bind_wire_format(self, wire_format: str,
                          topk_ratio: float) -> "LaxBackend":
         return dataclasses.replace(self, wire_format=wire_format,

@@ -7,6 +7,14 @@ no dynamic shapes inside jit.  Each bucket is padded to a multiple of the
 group size ``G`` so that one ``part_reduce``/``part_broadcast`` pair moves
 the whole bucket and every member owns an equal 1-D strip of it (the paper's
 §3.4 strip scheme, applied per bucket instead of per tensor).
+
+A bucket that holds one leaf of two or more dimensions, whose rows split
+into ``G`` blocks of whole sublane tiles (:func:`row_blocks`), keeps the
+leaf's 2-D ``(rows, cols)`` view as its buffer instead: member ``i``'s strip
+is row block ``i``, which holds exactly the elements of the 1-D strip ``i``
+in the same order.  A TPU lays a 2-D array out in ``(8, 128)`` tiles (for
+4-byte elements) and a 1-D one in ``(1024,)`` tiles, so flattening a weight
+is a physical copy there, and viewing it as ``(rows, cols)`` is not.
 """
 from __future__ import annotations
 
@@ -135,11 +143,46 @@ class LeafSlot:
                                  # only planning, e.g. the sweep benchmark)
 
 
+#: rows of one sublane tile by element bytes: the TPU's tiled 2-D layout
+SUBLANE_ROWS = {4: 8, 2: 16, 1: 32}
+
+
+def row_blocks(shape: Sequence[int], itemsize: int,
+               group: int) -> Optional[Tuple[int, int]]:
+    """``(rows, cols)`` of a leaf's 2-D view when its rows
+    (``prod(shape[:-1])``) split into ``group`` blocks of whole sublane
+    tiles, so each member's strip is a tile-aligned row block; else None
+    (1-D leaves, and rows that do not split)."""
+    tile = SUBLANE_ROWS.get(itemsize)
+    if len(shape) < 2 or tile is None:
+        return None
+    rows = math.prod(shape[:-1])
+    if rows % group or (rows // group) % tile:
+        return None
+    return rows, shape[-1]
+
+
 @dataclass(frozen=True)
 class Bucket:
     slots: Tuple[LeafSlot, ...]
     size: int                  # payload elements (sum of slot sizes)
-    padded_size: int           # size rounded up to a multiple of the group
+    shape: Tuple[int, ...]     # the fusion buffer's: (size rounded up to a
+                               # multiple of the group,), or a row-blocked
+                               # leaf's (rows, cols)
+
+    @property
+    def padded_size(self) -> int:
+        return math.prod(self.shape)
+
+    @property
+    def row_blocked(self) -> bool:
+        """Whether the buffer is one leaf's 2-D view, split by rows."""
+        return len(self.shape) == 2
+
+    def strip_shape(self, group: int) -> Tuple[int, ...]:
+        """One member's strip: ``(padded_size / G,)`` or ``(rows / G,
+        cols)``."""
+        return (self.shape[0] // group,) + self.shape[1:]
 
     @property
     def trigger_index(self) -> int:
@@ -182,6 +225,26 @@ class BucketPlan:
     def total_padded(self) -> int:
         return sum(b.padded_size for b in self.buckets)
 
+    @property
+    def row_block_share(self) -> float:
+        """Share of the payload elements in row-blocked buckets."""
+        total = self.total_elements
+        return (sum(b.size for b in self.buckets if b.row_blocked) / total
+                if total else 0.0)
+
+
+def _bucket(slots: List[LeafSlot], size: int, group: int,
+            itemsize: int) -> Bucket:
+    """A bucket of ``slots``: row-blocked when it holds one leaf that
+    :func:`row_blocks` splits, else a padded 1-D buffer."""
+    if len(slots) == 1:
+        s = slots[0]
+        isz = itemsize if s.dtype is None else np.dtype(s.dtype).itemsize
+        rc = row_blocks(s.shape, isz, group)
+        if rc is not None:
+            return Bucket(tuple(slots), size, rc)
+    return Bucket(tuple(slots), size, (padded_size(size, group),))
+
 
 def plan_buckets(tree: Any, group: int, bucket_bytes: int,
                  itemsize: int = 4) -> BucketPlan:
@@ -200,8 +263,7 @@ def plan_buckets(tree: Any, group: int, bucket_bytes: int,
     def close():
         nonlocal slots, fill, fill_bytes
         if slots:
-            buckets.append(Bucket(tuple(slots), fill,
-                                  padded_size(fill, group)))
+            buckets.append(_bucket(slots, fill, group, itemsize))
         slots, fill, fill_bytes = [], 0, 0
 
     for i, leaf in enumerate(leaves):
@@ -213,9 +275,9 @@ def plan_buckets(tree: Any, group: int, bucket_bytes: int,
         nbytes = size * isz
         if cap <= 0:
             # fusion disabled: per-tensor buckets (legacy schedule)
-            buckets.append(Bucket(
-                (LeafSlot(i, tuple(leaf.shape), size, 0, dt_name),), size,
-                padded_size(size, group)))
+            buckets.append(_bucket(
+                [LeafSlot(i, tuple(leaf.shape), size, 0, dt_name)], size,
+                group, itemsize))
             continue
         if slots and (fill_bytes + nbytes > cap or dt_name != cur_dtype):
             close()
@@ -230,7 +292,11 @@ def plan_buckets(tree: Any, group: int, bucket_bytes: int,
 
 
 def pack_bucket(flat_leaves: Sequence[jax.Array], bucket: Bucket) -> jax.Array:
-    """Concatenate the bucket's leaves into one padded 1-D fusion buffer."""
+    """Concatenate the bucket's leaves into one padded 1-D fusion buffer;
+    a row-blocked bucket's buffer is its leaf viewed as ``(rows, cols)``.
+    ``flat_leaves`` is indexed by flat tree position."""
+    if bucket.row_blocked:
+        return flat_leaves[bucket.slots[0].index].reshape(bucket.shape)
     parts = [flat_leaves[s.index].reshape(-1) for s in bucket.slots]
     pad = bucket.padded_size - bucket.size
     if pad:
@@ -246,8 +312,11 @@ def unpack_buckets(buffers: Sequence[jax.Array],
     out: List[jax.Array] = [None] * plan.n_leaves
     for buf, bucket in zip(buffers, plan.buckets):
         for s in bucket.slots:
-            leaf = jax.lax.slice(
-                buf, (s.offset,), (s.offset + s.size,)).reshape(s.shape)
+            if bucket.row_blocked:
+                leaf = buf.reshape(s.shape)
+            else:
+                leaf = jax.lax.slice(
+                    buf, (s.offset,), (s.offset + s.size,)).reshape(s.shape)
             if s.dtype is not None and leaf.dtype != np.dtype(s.dtype):
                 leaf = leaf.astype(s.dtype)
             out[s.index] = leaf

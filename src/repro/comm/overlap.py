@@ -46,7 +46,13 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.comm.bucketer import Bucket, BucketPlan, CommConfig, plan_buckets
+from repro.comm.bucketer import (
+    Bucket,
+    BucketPlan,
+    CommConfig,
+    pack_bucket,
+    plan_buckets,
+)
 from repro.comm.schedule import Schedule, make_schedule, reduce_mean
 from repro.core.collectives import AxisNames
 from repro.telemetry import scopes
@@ -100,11 +106,8 @@ def _bucket_tap(bucket: Bucket, sched: Schedule, wire_dtype, G: int):
 
     def bwd(_, ct):
         with jax.named_scope(scopes.REDUCE):
-            parts = [c.reshape(-1) for c in ct]
-            pad = bucket.padded_size - bucket.size
-            if pad:
-                parts.append(jnp.zeros((pad,), parts[0].dtype))
-            buf = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+            buf = pack_bucket({s.index: c for s, c in zip(bucket.slots, ct)},
+                              bucket)
             strip = reduce_mean(sched, buf, wire_dtype, G)
         # leaf cotangents pass through untouched — upstream backprop is
         # unaffected; the strip rides the sink's gradient channel
@@ -147,7 +150,7 @@ def make_overlap_grad(loss_fn: Callable, axes: AxisNames, comm: CommConfig,
             with jax.named_scope(scopes.FWD):
                 return loss_fn(jax.tree.unflatten(treedef, out), batch)
 
-        sinks = tuple(jnp.zeros((b.padded_size // G,), jnp.float32)
+        sinks = tuple(jnp.zeros(b.strip_shape(G), jnp.float32)
                       for b in plan.buckets)
         loss, strips = jax.value_and_grad(hooked_loss, argnums=1)(
             tuple(flat), sinks)

@@ -2,7 +2,9 @@
 
 Both schedules implement the same contract, to be called INSIDE
 ``jax.shard_map``: ``reduce`` turns a replicated-shape fusion buffer of
-partial sums into this member's 1-D strip (sum over the group, fp32 out),
+partial sums into this member's strip (sum over the group, fp32 out) — a
+1-D chunk, or a row block of a row-blocked ``(rows, cols)`` buffer
+(``comm.bucketer.row_blocks``),
 ``broadcast`` is its exact inverse on updated strips, and ``owner_index`` is
 the flat strip index the member owns — ``reduce`` scatters strip ``i`` to
 the member whose ``owner_index() == i``, and params must be sliced with the
@@ -45,6 +47,18 @@ from repro.comm.backends import CollectiveBackend, LaxBackend, get_backend
 from repro.core.collectives import AxisNames, axis_size, flat_group_index
 
 
+def _by_rows(backend: CollectiveBackend, op, x: jax.Array,
+             axes: AxisNames) -> jax.Array:
+    """Backend collective ``op`` along dim 0 of ``x``.  A backend whose
+    ``takes_rows`` is false (the ring kernels, compressed wires, gossip)
+    gets a row-blocked buffer flattened — chunk i of the flat buffer is
+    row block i, so it moves exactly the 1-D buffer's bytes — and the
+    result is viewed back as rows of ``x``'s width."""
+    if x.ndim == 1 or getattr(backend, "takes_rows", False):
+        return op(x, axes, dim=0)
+    return op(x.reshape(-1), axes, dim=0).reshape(-1, *x.shape[1:])
+
+
 def group_axes(mesh: Mesh, data_axes) -> Tuple[Tuple[str, ...], AxisNames, int]:
     """(axes, axis_arg, G) for the data-parallel group actually present on
     ``mesh``: requested axes filtered to the mesh, the single-name-or-tuple
@@ -72,12 +86,13 @@ class FlatSchedule:
         return flat_group_index(self.axes)
 
     def reduce(self, buf: jax.Array, wire_dtype=jnp.float32) -> jax.Array:
-        strip = self.backend.part_reduce(buf.astype(wire_dtype), self.axes,
-                                         dim=0)
+        strip = _by_rows(self.backend, self.backend.part_reduce,
+                         buf.astype(wire_dtype), self.axes)
         return strip.astype(jnp.float32)
 
     def broadcast(self, strip: jax.Array) -> jax.Array:
-        return self.backend.part_broadcast(strip, self.axes, dim=0)
+        return _by_rows(self.backend, self.backend.part_broadcast, strip,
+                        self.axes)
 
 
 @dataclass(frozen=True)
@@ -99,15 +114,21 @@ class HierarchicalSchedule:
                 + lax.axis_index(self.outer))
 
     def reduce(self, buf: jax.Array, wire_dtype=jnp.float32) -> jax.Array:
-        in_pod = self.inner_backend.part_reduce(buf.astype(wire_dtype),
-                                                self.inner, dim=0)
+        # a row-blocked buffer: row block d of the in-pod level splits
+        # into the cross-pod level's blocks, so block d*G_out + p lands
+        # on member (p, d), as the flat form's chunks do
+        in_pod = _by_rows(self.inner_backend, self.inner_backend.part_reduce,
+                          buf.astype(wire_dtype), self.inner)
         # cross-pod hop: strip bytes only, always fp32 accumulate
-        return self.outer_backend.part_reduce(in_pod.astype(jnp.float32),
-                                              self.outer, dim=0)
+        return _by_rows(self.outer_backend, self.outer_backend.part_reduce,
+                        in_pod.astype(jnp.float32), self.outer)
 
     def broadcast(self, strip: jax.Array) -> jax.Array:
-        in_pod = self.outer_backend.part_broadcast(strip, self.outer, dim=0)
-        return self.inner_backend.part_broadcast(in_pod, self.inner, dim=0)
+        in_pod = _by_rows(self.outer_backend,
+                          self.outer_backend.part_broadcast, strip,
+                          self.outer)
+        return _by_rows(self.inner_backend, self.inner_backend.part_broadcast,
+                        in_pod, self.inner)
 
 
 Schedule = Union[FlatSchedule, HierarchicalSchedule]

@@ -80,9 +80,14 @@ from repro.telemetry import scopes
 
 DEFAULT_COMM = CommConfig()
 
+#: gauge of the process recorder: share of the parameter elements whose
+#: bucket is row-blocked (``comm.bucketer.row_blocks``), set by
+#: ``api.compile_run``
+ROW_BLOCK_SHARE = "update.row_block_share"
+
 
 def owner_perm(hierarchical: bool, axes_sizes) -> Optional[np.ndarray]:
-    """Row j of a (G, n/G) state tensor lands on the member at flat mesh
+    """Row j of a (G, ...) strip state tensor lands on the member at flat mesh
     index j, but under the hierarchical schedule that member OWNS strip
     owner_index = d*G_out + p — so value-initialized optimizer state must
     be laid out in owner order (zeros-init state is insensitive to this).
@@ -142,7 +147,9 @@ class UpdatePlan:
         return _state_spec(s, self.axis_arg)
 
     def init_fn(self, params):
-        """(G, n/G) fusion-buffer strip state placed on the mesh — shared
+        """(G, *strip) fusion-buffer strip state placed on the mesh: (G,
+        n/G) for a 1-D bucket, (G, rows/G, cols) for a row-blocked one
+        (``comm.bucketer.row_blocks``) — shared
         by every mode (all consume the same plan and owner layout, so a
         checkpoint written by one path restores into another).  Traceable:
         ``jax.eval_shape`` gives the placed state's shapes from param
@@ -152,8 +159,9 @@ class UpdatePlan:
         def _strip_init(params):
             plan = self.buckets(params)
             flat = jax.tree.leaves(params)
-            # (G, n/G) strips: dim 0 sharded over the data axes
-            strips = [pack_bucket(flat, b).reshape(self.G, -1)
+            # (G, *strip): dim 0 sharded over the data axes
+            strips = [pack_bucket(flat, b).reshape(
+                          (self.G,) + b.strip_shape(self.G))
                       for b in plan.buckets]
             if perm is not None:
                 strips = [s[perm] for s in strips]
@@ -179,17 +187,17 @@ class UpdatePlan:
               opt_state, lr):
         """Phases 2–3: slice this member's param strips, run the serial
         optimizer on its local state row (elementwise, so fusing tensors
-        into one buffer does not change the math).  ``opt_state`` enters in
-        shard_map-local layout (strips as (1, n/G) rows) and the new state
-        leaves the same way."""
+        into one buffer, or blocking one by rows, does not change the
+        math).  ``opt_state`` enters in shard_map-local layout (strips as
+        (1, *strip) rows) and the new state leaves the same way."""
         flat_params = jax.tree.leaves(params)
         with jax.named_scope(scopes.APPLY):
             i = sched.owner_index()
             p_strips = []
             for b in plan.buckets:
-                pbuf = pack_bucket(flat_params, b)
-                n = b.padded_size // self.G
-                p_strips.append(lax.dynamic_slice(pbuf, (i * n,), (n,)))
+                n = b.strip_shape(self.G)[0]
+                p_strips.append(lax.dynamic_slice_in_dim(
+                    pack_bucket(flat_params, b), i * n, n))
             s_local = jax.tree.map(
                 lambda s: s[0] if s.ndim >= 2 else s, opt_state)
             new_p_strips, new_state = self.optimizer.update(
@@ -300,7 +308,7 @@ def make_stale_sync_update(optimizer, mesh: Mesh, data_axes=("data",),
 
     opt_state wraps the zero1 strip state:
 
-        {"stale":  per-bucket (G, n/G) carried mean-gradient strips,
+        {"stale":  per-bucket (G, *strip) carried mean-gradient strips,
          "synced": int32 flag — 0 until a reduce has been carried,
          "zero1":  the inner strip state (BIT-identical layout to the
                    synchronous modes', so zero1 checkpoints resume here
@@ -320,7 +328,7 @@ def make_stale_sync_update(optimizer, mesh: Mesh, data_axes=("data",),
         plan = up.buckets(params)
         sh = NamedSharding(mesh, P(up.axis_arg))
         stale = tuple(
-            jax.device_put(jnp.zeros((up.G, b.padded_size // up.G),
+            jax.device_put(jnp.zeros((up.G,) + b.strip_shape(up.G),
                                      jnp.float32), sh)
             for b in plan.buckets)
         # the flag is committed replicated so restore can re-place onto
@@ -369,7 +377,8 @@ def make_topk_ef_update(optimizer, mesh: Mesh, data_axes=("data",),
 
         {"residual": per-bucket (G, padded_size) f32 — row p is member p's
                      local unsent gradient mass (sharded dim 0, so each
-                     member materializes one bucket-sized row),
+                     member materializes one bucket-sized row; a
+                     row-blocked bucket's too, flattened),
          "zero1":    the inner strip state (BIT-identical layout to the
                      synchronous modes', so zero1 checkpoints resume here
                      with a zero residual — see ``api.run``)}
@@ -408,7 +417,8 @@ def make_topk_ef_update(optimizer, mesh: Mesh, data_axes=("data",),
         g_strips, new_res = [], []
         for b, res in zip(plan.buckets, opt_state["residual"]):
             with jax.named_scope(scopes.REDUCE):
-                buf = pack_bucket(flat_grads, b).astype(jnp.float32) + res[0]
+                buf = (pack_bucket(flat_grads, b).reshape(-1)
+                       .astype(jnp.float32) + res[0])
                 # floor G: every wire chunk must get at least one entry,
                 # and the per-chunk k the backend re-selects with (ratio *
                 # n/G, floored at 1) then carries at least the bucket's k/G
@@ -420,8 +430,9 @@ def make_topk_ef_update(optimizer, mesh: Mesh, data_axes=("data",),
                                  floor=up.G)
                 kept = topk_mask_ref(buf, k)
                 new_res.append((buf - kept)[None])
-                g_strips.append(reduce_mean(sched, kept,
-                                            up.comm.wire_dtype, up.G))
+                g_strips.append(reduce_mean(
+                    sched, kept, up.comm.wire_dtype, up.G).reshape(
+                        b.strip_shape(up.G)))
         new_p_strips, new_inner = up.apply(sched, plan, params, g_strips,
                                            opt_state["zero1"], lr)
         new_params = up.broadcast(sched, plan, params, new_p_strips)
@@ -432,6 +443,6 @@ def make_topk_ef_update(optimizer, mesh: Mesh, data_axes=("data",),
 
 
 def _state_spec(s, axis_arg) -> P:
-    # strip tensors are (G, n/G): dim 0 sharded; scalars (e.g. AdamW
+    # strip tensors are (G, *strip): dim 0 sharded; scalars (e.g. AdamW
     # step count, the staleness flag) replicated
     return P(axis_arg) if getattr(s, "ndim", 0) >= 2 else P()

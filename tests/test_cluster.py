@@ -223,6 +223,111 @@ def test_replan_strip_state_full_state_matches_ginvariant_run():
     assert "OK" in out
 
 
+def test_row_blocked_strip_state_replans_and_restores_as_flat(tmp_path):
+    """A row-blocked bucket's strip state saved at G=2, replanned to G=4 and
+    restored, and a zero1 checkpoint restored into the overlapped mode,
+    both give the flat path's values exactly — the flat path being the same
+    tree with every leaf 1-D, so that no bucket is row-blocked — and the
+    next step from them the flat path's params."""
+    out = run_py(f"""
+        import numpy as np
+        import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        from repro.checkpoint import ckpt as ckpt_lib
+        from repro.checkpoint.replan import replan_strip_state, world_meta
+        from repro.comm.bucketer import CommConfig, plan_buckets
+        from repro.optim import MomentumSGD
+        from repro.optim.dist import make_distributed_update, \\
+            make_overlapped_update
+        from repro.optim.schedule import constant
+        from repro.train import make_overlapped_train_step
+
+        # a: row-blocked at G=2 and G=4; h: at G=2 only (4 rows a block
+        # at G=4); b: 1-D
+        shapes = {{"a": (32, 24), "b": (16,), "h": (16, 8)}}
+        rng = np.random.default_rng(0)
+        params = {{k: jnp.asarray(rng.normal(size=s), jnp.float32)
+                   for k, s in shapes.items()}}
+        grads = jax.tree.map(jnp.cos, params)
+        x = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
+        opt = MomentumSGD(momentum=0.9)
+        comm = CommConfig(bucket_bytes=64)
+        assert [b.row_blocked for b in plan_buckets(params, 2, 64).buckets
+                ] == [True, False, True]
+        assert [b.row_blocked for b in plan_buckets(params, 4, 64).buckets
+                ] == [True, False, False]
+
+        def flat(tree):
+            return {{k: v.reshape(-1) for k, v in tree.items()}}
+
+        def mesh_of(G):
+            return jax.make_mesh((G,), ("data",), devices=jax.devices()[:G],
+                                 axis_types=(AxisType.Auto,))
+
+        def loss(p, batch):
+            return sum(jnp.sum(jnp.sin(v.reshape(shapes[k])).reshape(-1)[:4]
+                               * batch["x"].sum(0)) for k, v in p.items())
+
+        def saved_at_g2(tree, d):
+            mesh = mesh_of(2)
+            init, upd = make_distributed_update(opt, mesh, comm=comm)
+            with jax.set_mesh(mesh):
+                st = init(tree)
+                for _ in range(2):
+                    tree, st = jax.jit(upd)(tree, flat(grads) if tree[
+                        "a"].ndim == 1 else grads, st, 0.05)
+            ckpt_lib.save(d, 2, params=tree, opt_state=st)
+            return tree
+
+        def replanned_at_g4(tree, d):
+            tree = ckpt_lib.restore(d, 2, params=tree)[0]["params"]
+            mesh = mesh_of(4)
+            init, upd = make_distributed_update(opt, mesh, comm=comm)
+            with jax.set_mesh(mesh):
+                tpl = jax.eval_shape(init, tree)
+                st = replan_strip_state(
+                    tpl, ckpt_lib.restore_loose(d, 2, "opt_state", tpl),
+                    plan_buckets(tree, 4, 64), world_meta([2], False, 64),
+                    world_meta([4], False, 64))
+                g = flat(grads) if tree["a"].ndim == 1 else grads
+                p, _ = jax.jit(upd)(tree, g, st, 0.05)
+            return st, p
+
+        def overlapped_at_g2(tree, d):
+            mesh = mesh_of(2)
+            c = CommConfig(bucket_bytes=64, overlap=True)
+            init, local = make_overlapped_update(opt, mesh, comm=c)
+            step = make_overlapped_train_step(loss, constant(0.05), mesh,
+                                              ("data",), c, local,
+                                              grad_clip=0)
+            with jax.set_mesh(mesh):
+                tpl = jax.eval_shape(init, tree)
+                trees, _ = ckpt_lib.restore(d, 2, params=tree, opt_state=tpl)
+                p, _, _ = jax.jit(step)(trees["params"], trees["opt_state"],
+                                        2, {{"x": x}})
+            return trees["opt_state"], p
+
+        d_rb, d_fl = {str(tmp_path / "rb")!r}, {str(tmp_path / "flat")!r}
+        p_rb, p_fl = saved_at_g2(params, d_rb), saved_at_g2(flat(params), d_fl)
+        for tag, fn in (("replan G=2 -> 4", replanned_at_g4),
+                        ("zero1 -> overlapped", overlapped_at_g2)):
+            s_rb, q_rb = fn(p_rb, d_rb)
+            s_fl, q_fl = fn(p_fl, d_fl)
+            for a, b in zip(jax.tree.leaves(s_rb), jax.tree.leaves(s_fl)):
+                a, b = np.asarray(a), np.asarray(b)
+                np.testing.assert_array_equal(
+                    a.reshape(b.shape[0], -1) if b.ndim >= 2 else a, b,
+                    err_msg=tag)
+            for k in shapes:
+                np.testing.assert_array_equal(
+                    np.asarray(q_rb[k]).reshape(-1), np.asarray(q_fl[k]),
+                    err_msg=f"{{tag}}/{{k}}")
+            print(tag, "OK")
+        print("OK")
+    """, devices=4)
+    assert out.rstrip().endswith("OK"), out
+
+
 # ---------------------------------------------------------------------------
 # make_host_mesh device-drop fix
 # ---------------------------------------------------------------------------

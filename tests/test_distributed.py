@@ -6,6 +6,9 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
+import pytest
+
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -642,14 +645,16 @@ def test_phase_pipeline_bit_exact_vs_seed_builders():
     implementations (per-tensor schedule, bucketed monolithic update,
     bucketed apply+broadcast tail) are copied verbatim below and both
     stacks run two momentum steps from the same start; params and state
-    leaves must match with assert_array_equal — no tolerance."""
+    leaves must match with assert_array_equal — no tolerance.  The seed
+    packs every bucket into a 1-D buffer; the pipeline keeps a row-blocked
+    bucket's (G, rows/G, cols) state, compared after reshape(G, -1)."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax import lax
         from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.comm import CommConfig
-        from repro.comm.bucketer import pack_bucket, plan_buckets, \\
-            unpack_buckets
+        from repro.comm import bucketer
+        from repro.comm.bucketer import plan_buckets
         from repro.comm.schedule import group_axes, make_schedule
         from repro.core.collectives import flatten_pad, strip_broadcast, \\
             strip_reduce
@@ -658,6 +663,22 @@ def test_phase_pipeline_bit_exact_vs_seed_builders():
             make_overlapped_update, owner_perm
 
         # ---- seed builders, verbatim from the pre-refactor module ----
+        def pack_bucket(flat_leaves, bucket):
+            parts = [flat_leaves[s.index].reshape(-1) for s in bucket.slots]
+            pad = bucket.padded_size - bucket.size
+            if pad:
+                parts.append(jnp.zeros((pad,), parts[0].dtype))
+            return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+
+        def unpack_buckets(buffers, plan):
+            out = [None] * plan.n_leaves
+            for buf, bucket in zip(buffers, plan.buckets):
+                for s in bucket.slots:
+                    out[s.index] = lax.slice(
+                        buf, (s.offset,), (s.offset + s.size,)).reshape(
+                            s.shape)
+            return out
+
         def seed_bucketed_init(optimizer, mesh, axes, axis_arg, G, comm):
             perm = owner_perm(comm.hierarchical,
                               [mesh.shape[a] for a in axes])
@@ -777,7 +798,10 @@ def test_phase_pipeline_bit_exact_vs_seed_builders():
         opt = MomentumSGD(momentum=0.9, weight_decay=0.01)
         params = {"w": jnp.arange(12, dtype=jnp.float32).reshape(3, 4) / 7,
                   "b": jnp.ones((5,), jnp.float32),
-                  "c": jnp.cos(jnp.arange(40, dtype=jnp.float32))}
+                  "c": jnp.cos(jnp.arange(40, dtype=jnp.float32)),
+                  # 32 rows: 8 a member at G = 4, row-blocked alone
+                  "r": jnp.sin(jnp.arange(128, dtype=jnp.float32)
+                               ).reshape(32, 4)}
         g1 = jax.tree.map(jnp.cos, params)
         g2 = jax.tree.map(jnp.sin, params)
 
@@ -798,8 +822,10 @@ def test_phase_pipeline_bit_exact_vs_seed_builders():
             # seed per-tensor state is tree-shaped, the pipeline's is a
             # strip list — leaves match positionally
             for a, b in zip(jax.tree.leaves(ss), jax.tree.leaves(sn)):
-                np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b), err_msg=f"{tag}/state")
+                a, b = np.asarray(a), np.asarray(b)
+                if b.ndim >= 2:
+                    b = b.reshape(b.shape[0], -1)
+                np.testing.assert_array_equal(a, b, err_msg=f"{tag}/state")
 
         check("per-tensor",
               seed_per_tensor(opt, mesh, ("pod", "data")),
@@ -826,13 +852,15 @@ def test_phase_pipeline_bit_exact_vs_seed_builders():
             opt, mesh, data_axes=("pod", "data"), comm=comm)
         init_seed = seed_bucketed_init(opt, mesh, axes, axis_arg, G, comm)
 
-        def driver(local_update):
+        def driver(local_update, pack):
+            # each stack's strips in its own layout: the seed's 1-D, the
+            # pipeline's row blocks
             def _inner(params, grads, opt_state, lr):
                 plan = plan_buckets(params, G, comm.bucket_bytes)
                 sched = make_schedule(axis_arg, comm.hierarchical,
                                       comm.backend, comm.cross_backend)
                 flat_grads = jax.tree.leaves(grads)
-                g_strips = [sched.reduce(pack_bucket(flat_grads, b),
+                g_strips = [sched.reduce(pack(flat_grads, b),
                                          comm.wire_dtype) / G
                             for b in plan.buckets]
                 return local_update(params, g_strips, opt_state, lr)
@@ -855,8 +883,8 @@ def test_phase_pipeline_bit_exact_vs_seed_builders():
                               opt_state, lr)
 
         check("overlap-tail",
-              (init_seed, driver(seed_local)),
-              (init_new, driver(local_new)))
+              (init_seed, driver(seed_local, pack_bucket)),
+              (init_new, driver(local_new, bucketer.pack_bucket)))
         print("OK")
     """)
 
@@ -975,3 +1003,149 @@ def test_stale_sync_applies_previous_steps_gradient():
                                        rtol=1e-5, atol=1e-7, err_msg=k)
         print("OK")
     """, devices=4)
+
+
+# ---------------------------------------------------------------------------
+# row-block strips: a single-leaf bucket keeps the leaf's (rows, cols) view
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("shape,dtype,group,want", [
+    ((2048, 2048), "float32", 4, (2048, 2048)),
+    ((3, 3, 512, 512), "float32", 4, (4608, 512)),
+    ((32, 24), "float32", 4, (32, 24)),          # 8 rows a block
+    ((16, 8), "float32", 4, None),               # 4 rows: under a tile
+    ((12, 8), "float32", 1, None),               # 12 rows: not whole tiles
+    ((440, 2048), "float32", 1, (440, 2048)),
+    ((440, 2048), "float32", 4, None),           # 110 rows a block
+    ((64, 128), "bfloat16", 4, (64, 128)),       # 16 rows: one bf16 tile
+    ((32, 128), "bfloat16", 4, None),            # 8 rows: half a bf16 tile
+    ((64, 128), "int8", 2, (64, 128)),           # 32 rows: one int8 tile
+    ((2048,), "float32", 1, None),               # 1-D stays flat
+])
+def test_row_block_rule_on_plan_buckets(shape, dtype, group, want):
+    import jax
+    import jax.numpy as jnp
+    from repro.comm.bucketer import plan_buckets
+    leaf = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    size = int(np.prod(shape))
+    # alone in its bucket: row-blocked by the rule, else the 1-D buffer
+    b, = plan_buckets({"w": leaf}, group, 4 * 2**20).buckets
+    assert b.row_blocked == (want is not None)
+    assert b.shape == (want if want else (b.padded_size,))
+    assert b.strip_shape(group)[0] * group == b.shape[0]
+    if want:
+        assert b.padded_size == size
+    # sharing a bucket with another leaf: always the 1-D buffer
+    two = {"a": jax.ShapeDtypeStruct((8,), jnp.dtype(dtype)), "w": leaf}
+    b, = plan_buckets(two, group, 1 << 30).buckets
+    assert not b.row_blocked and b.shape == (b.padded_size,)
+
+
+_ROW_BLOCK_EQUIV = """
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
+    from repro.comm import CommConfig
+    from repro.comm.bucketer import plan_buckets
+    from repro.optim import MomentumSGD
+    from repro.optim.dist import make_distributed_update, \\
+        make_overlapped_update, make_stale_sync_update, make_topk_ef_update
+    from repro.optim.schedule import constant
+    from repro.train import make_overlapped_train_step
+
+    G = {G}
+    rng = np.random.default_rng(G)
+    shapes = {{"a": (32, 24),          # row-blocked: 32 / G rows of 8
+               "b": (16,), "c": (40,),
+               "k": (3, 3, 32, 16),    # row-blocked: 288 / G rows
+               "u": (12, 8)}}          # 12 rows never split into tiles
+    params = {{k: jnp.asarray(rng.normal(size=s), jnp.float32)
+               for k, s in shapes.items()}}
+    x = jnp.asarray(rng.normal(size=(8 * G, 4)), jnp.float32)
+    grads = [jax.tree.map(lambda p, t=t: jnp.cos(p * (t + 1)), params)
+             for t in range(2)]
+    opt = MomentumSGD(momentum=0.9, weight_decay=0.01)
+    assert [b.row_blocked for b in plan_buckets(params, G, 64).buckets] == [
+        True, False, False, True, False]
+
+    def flat(tree):
+        # every leaf 1-D: every bucket takes the flat path
+        return {{k: v.reshape(-1) for k, v in tree.items()}}
+
+    def monolithic(make, comm):
+        def run(mesh, axes, tree, gs):
+            init_fn, update_fn = make(opt, mesh, data_axes=axes, comm=comm)
+            st = init_fn(tree)
+            for t, g in enumerate(gs):
+                tree, st = jax.jit(update_fn)(tree, g, st, 0.05, t)
+            return tree, st
+        return run
+
+    def loss(p, batch):
+        # sees each leaf in its own shape, whatever shape the tree holds
+        return sum(jnp.sum(jnp.sin(v.reshape(shapes[k])).reshape(-1)[:4]
+                           * batch["x"].sum(0)) for k, v in p.items())
+
+    def overlapped(comm):
+        def run(mesh, axes, tree, gs):
+            init_fn, local = make_overlapped_update(opt, mesh,
+                                                    data_axes=axes, comm=comm)
+            step = jax.jit(make_overlapped_train_step(
+                loss, constant(0.05), mesh, axes, comm, local, grad_clip=0))
+            st = init_fn(tree)
+            for t in range(len(gs)):
+                tree, st, _ = step(tree, st, t, {{"x": x}})
+            return tree, st
+        return run
+
+    cases = [
+        ("zero1", monolithic(make_distributed_update,
+                             CommConfig(bucket_bytes=64))),
+        ("per-tensor", monolithic(make_distributed_update, None)),
+        ("pallas-ring", monolithic(make_distributed_update, CommConfig(
+            bucket_bytes=64, backend="pallas-ring"))),
+        ("int8", monolithic(make_distributed_update, CommConfig(
+            bucket_bytes=64, wire_format="int8"))),
+        ("stale-sync", monolithic(make_stale_sync_update,
+                                  CommConfig(bucket_bytes=64))),
+        ("topk", monolithic(make_topk_ef_update, CommConfig(
+            bucket_bytes=64, wire_format="topk"))),
+        ("overlap", overlapped(CommConfig(bucket_bytes=64, overlap=True)))]
+    meshes = {{name: ("data",) for name, _ in cases}}
+    if G == 4:
+        for backend in ("lax", "pallas-ring"):
+            tag = f"hierarchical/{{backend}}"
+            cases.append((tag, monolithic(
+                make_distributed_update, CommConfig(
+                    bucket_bytes=64, hierarchical=True, backend=backend))))
+            meshes[tag] = ("pod", "data")
+    for tag, run in cases:
+        axes = meshes[tag]
+        sizes = (G,) if len(axes) == 1 else (2, G // 2)
+        mesh = jax.make_mesh(sizes, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
+        with jax.set_mesh(mesh):
+            pr, sr = run(mesh, axes, params, grads)
+            pf, sf = run(mesh, axes, flat(params), [flat(g) for g in grads])
+        for k in params:
+            np.testing.assert_array_equal(
+                np.asarray(pr[k]).reshape(-1), np.asarray(pf[k]),
+                err_msg=f"{{tag}}/params/{{k}}")
+        for a, b in zip(jax.tree.leaves(sr), jax.tree.leaves(sf)):
+            a, b = np.asarray(a), np.asarray(b)
+            if b.ndim >= 2:
+                # a row-blocked (G, rows/G, cols) strip holds the flat
+                # (G, n/G) strip's elements in the same order
+                a = a.reshape(b.shape[0], -1)
+            np.testing.assert_array_equal(a, b, err_msg=f"{{tag}}/state")
+        print(tag, "OK")
+    print("OK")
+"""
+
+
+@pytest.mark.parametrize("G", [1, 2, 4])
+def test_row_blocked_update_bitwise_equals_flat(G):
+    """Two momentum steps of every zero1 composition give params bitwise
+    equal to the flat path's, and strip state bitwise equal after
+    ``reshape(G, -1)``: the flat path is the same tree with every leaf
+    1-D, so no bucket is row-blocked there."""
+    out = run_py(_ROW_BLOCK_EQUIV.format(G=G), devices=G)
+    assert out.rstrip().endswith("OK"), out

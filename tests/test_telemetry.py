@@ -292,6 +292,33 @@ def test_run_step_and_fit_emit_step_dispatch_and_counters():
     assert rec.hist("span/step.dispatch_s").count >= 4
 
 
+@pytest.mark.parametrize("parallel,bucket_bytes,weights_share", [
+    # buckets small enough that each weight is alone in its own, and every
+    # smoke CD-DNN weight's rows split into whole tiles: all row-blocked
+    ("zero1", 1024, True),
+    # the default 4 MiB bucket fuses the whole smoke tree into one
+    ("zero1", None, False),
+    # serial builds no update plan
+    ("serial", None, False),
+])
+def test_compile_run_sets_the_row_block_share_gauge(parallel, bucket_bytes,
+                                                    weights_share):
+    from repro.api import RunSpec, compile_run
+    from repro.comm.bucketer import CommConfig
+    from repro.optim.dist import ROW_BLOCK_SHARE
+    from repro.telemetry import process_recorder
+    comm = None if bucket_bytes is None else CommConfig(
+        bucket_bytes=bucket_bytes)
+    run = compile_run(RunSpec(arch="cd-dnn", smoke=True, parallel=parallel,
+                              batch=4, comm=comm))
+    sizes = {k: v.size for k, v in run.params.items()}
+    run.close()
+    want = (sum(n for k, n in sizes.items() if k.endswith("_w"))
+            / sum(sizes.values())) if weights_share else 0.0
+    got = process_recorder().metrics()["gauges"][ROW_BLOCK_SHARE]
+    assert got == pytest.approx(want)
+
+
 def test_closed_run_detaches_its_sinks(tmp_path):
     from repro.api import RunSpec, compile_run
     from repro.telemetry import process_recorder, read_jsonl, trace_path

@@ -160,25 +160,36 @@ def _device_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+# the compiled steps: train CLI argv and chips
+STEPS = {
+    # the step chip_smoke.py trains on one chip
+    "vgg_a_one_chip": (chip_smoke.ONE_CHIP_ARGV, 1),
+    # zero1 with lax collectives on a (4, 1) data mesh
+    "vgg_a_four_chips": (chip_smoke.FOUR_CHIP_ARGV + ["--parallel", "zero1"]
+                         + chip_smoke.FOUR_CHIP_BACKENDS["lax"], 4),
+    "cd_dnn_one_chip": (["--arch", "cd-dnn", "--parallel", "zero1",
+                         "--batch", "1024", "--schedule", "constant"], 1),
+}
+
+
+def _step_of(topo, name):
+    argv, n_chips = STEPS[name]
+    return _train_step(topo.devices[:n_chips], argv)
+
+
 @pytest.fixture(scope="module")
 def vgg_a_one_chip(topo):
-    # the step chip_smoke.py trains on one chip
-    return _train_step(topo.devices[:1], chip_smoke.ONE_CHIP_ARGV)
+    return _step_of(topo, "vgg_a_one_chip")
 
 
 @pytest.fixture(scope="module")
 def vgg_a_four_chips(topo):
-    # zero1 with lax collectives on a (4, 1) data mesh
-    return _train_step(topo.devices[:4], chip_smoke.FOUR_CHIP_ARGV
-                       + ["--parallel", "zero1"]
-                       + chip_smoke.FOUR_CHIP_BACKENDS["lax"])
+    return _step_of(topo, "vgg_a_four_chips")
 
 
 @pytest.fixture(scope="module")
 def cd_dnn_one_chip(topo):
-    return _train_step(topo.devices[:1],
-                       ["--arch", "cd-dnn", "--parallel", "zero1",
-                        "--batch", "1024", "--schedule", "constant"])
+    return _step_of(topo, "cd_dnn_one_chip")
 
 
 def test_vgg_a_zero1_step_fits_one_chip(vgg_a_one_chip):
@@ -218,7 +229,8 @@ def scope_report(text):
     ``"layout-only"`` for a fusion that only moves or fills data (a
     relayout, a broadcast constant), ``"fused ops"`` for a multi-output
     fusion whose fused ops carry the op_names, ``"combined collective"``
-    for a collective the compiler rewrote — or ``"missing"``)."""
+    for a collective the compiler rewrote, alone or inside a fusion — or
+    ``"missing"``)."""
     from repro.telemetry.scopes import (COLLECTIVE, hlo_phases, parse_hlo,
                                         phase_of)
     comps = parse_hlo(text)
@@ -245,7 +257,10 @@ def scope_report(text):
                     why = "layout-only"
                 elif own is None and any(j.op_name for j in inner):
                     why = "fused ops"
-                elif own is None and collective:
+                elif own is None and (collective or any(
+                        COLLECTIVE.match(j.opcode) for j in inner)):
+                    # a reduce-scatter the compiler made an all-reduce
+                    # and a slice inside a fusion of its own
                     why = "combined collective"
                 else:
                     why = "missing"
@@ -334,6 +349,94 @@ def test_overlapped_step_names_its_phases_on_four_cpu_devices():
     assert all(p not in (None, "other") for p in coll.values()), coll
     assert {p for n, p in coll.items()
             if codes[n] == "reduce-scatter"} == {"reduce"}
+
+
+# ---------------------------------------------------------------------------
+# row-blocked buckets: the update moves no weight into another layout
+# ---------------------------------------------------------------------------
+_ARRAY = re.compile(r"[a-z]+\d*\[([\d,]*)\]\{([\d,]*)(?::([^}]*))?\}")
+_TILING = re.compile(r"T(?:\([\d,]+\))+")
+
+
+def _arrays(hlo_type):
+    """(dims, minor_to_major, tiling) of each array in an HLO type: one
+    array, or a tuple of them.  The memory space (``S(n)``) is left out:
+    moving an array between memories keeps its layout."""
+    out = []
+    for dims, m2m, tail in _ARRAY.findall(hlo_type):
+        tiling = _TILING.search(tail or "")
+        out.append((tuple(int(d) for d in dims.split(",") if d),
+                    tuple(int(d) for d in m2m.split(",") if d),
+                    tiling.group(0) if tiling else ""))
+    return out
+
+
+def _physical(dims, m2m, tiling):
+    """An array's arrangement in memory up to a bitcast.  Unit dimensions
+    do not matter; a row-major array tiled on its last two dimensions is
+    its ``(rows, cols)`` view, whatever leading dimensions make up its
+    rows, when its second-minor dimension is a whole number of tiles."""
+    keep = [d for d, n in enumerate(dims) if n != 1]
+    rank = {d: i for i, d in enumerate(keep)}
+    dims = tuple(dims[d] for d in keep)
+    m2m = tuple(rank[d] for d in m2m if d in rank)
+    tile = re.match(r"T\((\d+),(\d+)\)", tiling)
+    if (len(dims) >= 2 and m2m == tuple(reversed(range(len(dims))))
+            and tile and dims[-2] % int(tile.group(1)) == 0):
+        return (math.prod(dims[:-1]), dims[-1]), tiling
+    return dims, m2m, tiling
+
+
+def _relayouts(argv, n_chips, text):
+    """Copies, ``while`` loops and layout-only or dynamic-update-slice
+    fusions of the compiled step ``text`` whose result holds as many
+    elements as a row-blocked bucket's leaf, in another layout than the
+    leaf's: what the strip update would spend moving the weight."""
+    from repro.api.families import adapter_for
+    from repro.comm.bucketer import CommConfig, plan_buckets
+    from repro.configs import get_config
+    from repro.launch.train import parse_run_spec
+    from repro.telemetry.scopes import parse_hlo
+    spec = parse_run_spec(argv)
+    cfg = get_config(spec.arch)
+    params = jax.eval_shape(functools.partial(adapter_for(cfg).init, cfg),
+                            jax.random.PRNGKey(spec.seed))
+    comm = spec.comm if isinstance(spec.comm, CommConfig) else CommConfig()
+    plan = plan_buckets(params, n_chips, comm.bucket_bytes)
+    # the params lead the entry computation's arguments, in tree order
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
+    param_layouts = _arrays(entry.group(1))[:plan.n_leaves]
+    leaf = {}
+    for b in plan.buckets:
+        if b.row_blocked:
+            dims, m2m, tiling = param_layouts[b.slots[0].index]
+            leaf[b.size] = _physical(dims, m2m, tiling)
+    assert leaf, "no row-blocked bucket"
+    comps = parse_hlo(text)
+    types = dict(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) [\w\-]+\(",
+                            text, re.M))
+    found = {}
+    for instrs in comps.values():
+        for i in instrs:
+            inner = {j.opcode for c in i.callees for j in comps.get(c, [])}
+            if not (i.opcode in ("copy", "copy-start", "copy-done", "while")
+                    or i.opcode == "fusion" and (
+                        "dynamic-update-slice" in i.name
+                        or inner and inner <= LAYOUT_ONLY)):
+                continue
+            for dims, m2m, tiling in _arrays(types.get(i.name, "")):
+                n = math.prod(dims)
+                if n in leaf and _physical(dims, m2m, tiling) != leaf[n]:
+                    found[i.name] = (i.opcode, dims, m2m, tiling)
+    return found
+
+
+@pytest.mark.parametrize("step", list(STEPS))
+def test_update_moves_no_row_blocked_weight_into_another_layout(step,
+                                                                request):
+    argv, n_chips = STEPS[step]
+    text = request.getfixturevalue(step).as_text()
+    assert not _relayouts(argv, n_chips, text)
 
 
 @pytest.mark.parametrize("op_name,phase", [
