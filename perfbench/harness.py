@@ -3,10 +3,13 @@ reference, and the result line.
 
 A cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a
 configuration (``configs/<name>.json``, its family's reference in
-``families/<family>.py``) and a traffic mix (``traffic/<name>.json``); its
+``families/<family>.py``: ``program_config``, ``init``, ``loss`` and,
+beyond the conv/fc arithmetic of ``flops.py``, its own
+``step_flops_per_sample``) and a traffic mix (``traffic/<name>.json``,
+whose ``run`` may also name the optimizer and its weight decay); its
 limits are ``limits/<cell>.json`` and each per-layer metric is read by
-``metrics/<metric>.py``.  Nothing here names a cell, so a cell is added by
-adding those files.
+``metrics/<metric>.py``.  Nothing here names a cell, a family or a param,
+so a cell is added by adding those files.
 
 Set-up builds the program's run through ``repro.api.compile_run``, makes
 the traffic pool from the seed, and feeds it through the program's data
@@ -30,9 +33,8 @@ import sys
 import tempfile
 import time
 
-import numpy as np
-
 import compare
+import flops
 import hlo
 import reference
 import traffic as traffic_gen
@@ -86,6 +88,13 @@ def family(cfg: dict):
     return load_module("families", f"{cfg['family']}.py")
 
 
+def step_flops_per_sample(cfg: dict) -> int:
+    """FLOPs per sample of a training step: the family module's own count
+    where it keeps one, else ``flops.py``'s conv and fc arithmetic."""
+    count = getattr(family(cfg), "step_flops_per_sample", None)
+    return count(cfg) if count else flops.step_flops_per_sample(cfg)
+
+
 class CompileCounter:
     """Counts JAX's compile events (tracing, lowering, backend compiles)."""
 
@@ -114,11 +123,13 @@ def build(c: dict, seed: int):
     from repro.api import RunSpec, compile_run
     from repro.comm.bucketer import CommConfig
     r = c["traffic"]["run"]
+    # an absent key keeps RunSpec's default
+    chosen = {k: r[k] for k in ("optimizer", "weight_decay") if k in r}
     spec = RunSpec(arch=family(c["cfg"]).program_config(c["cfg"]),
                    parallel=r["parallel"], comm=CommConfig(**r["comm"]),
                    schedule=r["schedule"], lr=r["lr"],
                    momentum=r["momentum"], grad_clip=r["grad_clip"],
-                   batch=c["traffic"]["batch"], seed=seed)
+                   batch=c["traffic"]["batch"], seed=seed, **chosen)
     return compile_run(spec)
 
 
@@ -129,10 +140,9 @@ def feed(run, pool):
 
 
 def host_params(run) -> dict:
-    """The run's params on the host, by leaf name (both families keep a
-    flat dict, named as the references name theirs)."""
-    import jax
-    return {k: np.asarray(v) for k, v in jax.device_get(run.params).items()}
+    """The run's params on the host, by leaf name, named as the reference
+    names its own (``compare.named_leaves``)."""
+    return compare.named_leaves(run.params)
 
 
 def first_steps(run, batches) -> dict:

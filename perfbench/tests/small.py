@@ -2,7 +2,6 @@
 settings and limits of the real cells, with small layers and batches."""
 import copy
 
-import flops
 import harness
 
 CNN = {
@@ -34,6 +33,6 @@ def small_cell(name: str, cfg: dict, batch: int = 32) -> dict:
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         c = harness.cell(json.load(f), name)
     c["cfg"] = copy.deepcopy(cfg)
-    c["cfg"]["step_flops_per_sample"] = flops.step_flops_per_sample(cfg)
+    c["cfg"]["step_flops_per_sample"] = harness.step_flops_per_sample(cfg)
     c["traffic"] = dict(c["traffic"], batch=batch)
     return c

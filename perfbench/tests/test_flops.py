@@ -31,9 +31,21 @@ def test_vgg_a_is_mostly_convolution():
     assert flops.forward_macs(_cfg("cd-dnn"))["conv"] == 0
 
 
-@pytest.mark.parametrize("name", ["vgg-a", "cd-dnn"])
+def _benchmark_configs() -> dict:
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        return {c["name"]: c["file"] for c in json.load(f)["configs"]}
+
+
+@pytest.mark.parametrize("name", sorted(_benchmark_configs()))
 def test_recorded_counts_are_the_arithmetic(name):
-    cfg = _cfg(name)
+    """Every configuration the benchmark runs records the FLOP count of
+    its family: the family module's own, or ``flops.py``'s for the conv
+    and fc families, whose arithmetic is checked further."""
+    with open(os.path.join(harness.ROOT, _benchmark_configs()[name])) as f:
+        cfg = json.load(f)
+    assert cfg["step_flops_per_sample"] == harness.step_flops_per_sample(cfg)
+    if hasattr(harness.family(cfg), "step_flops_per_sample"):
+        return
     assert cfg["forward_macs_per_sample"] == flops.forward_macs(cfg)
     assert cfg["step_flops_per_sample"] == flops.step_flops_per_sample(cfg)
     # three passes of 2 FLOPs per multiply-add, less the first layer's
@@ -79,3 +91,13 @@ def test_program_config_is_the_registry_one(name, arch):
                                  "output_dim")
     for f in fields:
         assert getattr(built, f) == getattr(want, f)
+
+
+def test_a_family_module_keeps_its_own_count(monkeypatch):
+    """A family beyond conv and fc counts its own FLOPs; the harness takes
+    that count, and ``flops.py``'s only where the family keeps none."""
+    import types
+    own = types.SimpleNamespace(step_flops_per_sample=lambda cfg: 6 * cfg[
+        "params"])
+    monkeypatch.setattr(harness, "family", lambda cfg: own)
+    assert harness.step_flops_per_sample({"params": 7}) == 42
